@@ -32,23 +32,12 @@ Methodology (the numbers this file writes are compared across PRs):
   (``tick_exact_vs_python``); the pallas lane records its own contract
   (``decisions_exact`` vs the cache oracle + the associative latency
   reconstruction cross-check);
-* XLA:CPU runs with ``--xla_cpu_use_thunk_runtime=false`` (set below,
-  before the backend initializes): the legacy emitter compiles a scan body
-  into one LLVM function instead of dispatching per-op thunks — this is
-  the CPU-native codegen path the ROADMAP's 20x target called for.
 """
 
 from __future__ import annotations
 
-import os
-
-from xla_flags import enable_cpu_native_codegen
-
-# Must precede XLA:CPU client initialization (first jax computation) —
-# and in particular every ``repro``/``jax`` import below.
-enable_cpu_native_codegen()
-
 import json
+import os
 import time
 from typing import List, Tuple
 
@@ -567,7 +556,7 @@ def collect_fleet_derived(num_hosts: int = FLEET_HOSTS,
     mesh shape, fleet-pooled tail percentiles and the media counters.  No
     wall-clock numbers leak in, so the JSON is byte-identical across runs
     (CI-guarded); CI re-runs it scaled down and double-checks the bits."""
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     from repro.core.fabric import Fabric
     from repro.core.replay import (MetricsSpec, MultiHostReplay,
@@ -579,7 +568,7 @@ def collect_fleet_derived(num_hosts: int = FLEET_HOSTS,
                         write_frac=WRITE_FRAC)
     # on-device synthesis: the traced twin builds every host column as a
     # pure function of (seed, host, i) — no python per-access objects
-    with enable_x64():
+    with enable_x64(True):
         cols = [host_trace_jnp(spec, FLEET_SEED, h, accesses)
                 for h in range(num_hosts)]
         addrs = np.stack([np.asarray(a, np.int64) for a, _ in cols])
@@ -676,7 +665,6 @@ def bench_replay() -> List[Row]:
             "outstanding": 32,
             "block_sizes": list(BLOCK_SIZES),
             "repeats": REPEATS,
-            "xla_flags": os.environ.get("XLA_FLAGS", ""),
         },
         "target_speedup": TARGETS,
         "devices": {},
@@ -764,6 +752,9 @@ ALL = [bench_replay]
 if __name__ == "__main__":
     import sys
 
+    from repro.compile_cache import use_persistent_cache
+
+    use_persistent_cache()
     if "--availability-only" in sys.argv:
         # refresh just the derived availability lane, leaving every
         # previously recorded timing untouched

@@ -9,10 +9,8 @@ Row = Tuple[str, float, str]
 
 
 def bench_roofline_summary() -> List[Row]:
-    try:
-        from repro.launch.roofline import load_all
-    except Exception:
-        return []
+    from repro.launch.roofline import load_all
+
     rows: List[Row] = []
     for mesh in ("single", "multi"):
         for r in load_all("results/dryrun", mesh):

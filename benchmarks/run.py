@@ -12,12 +12,7 @@ from __future__ import annotations
 import importlib
 import sys
 
-from xla_flags import enable_cpu_native_codegen
-
-# CPU-native codegen for the scan-heavy replay lanes (see replay_bench):
-# must be in the environment before any section initializes the XLA CPU
-# client, so set it here rather than relying on module import order.
-enable_cpu_native_codegen()
+from repro.compile_cache import use_persistent_cache
 
 MODULES = [
     "benchmarks.paper_figures",
@@ -32,13 +27,10 @@ MODULES = [
 
 def main() -> None:
     only = sys.argv[1] if len(sys.argv) > 1 else None
+    use_persistent_cache()
     print("name,us_per_call,derived")
     for modname in MODULES:
-        try:
-            mod = importlib.import_module(modname)
-        except Exception as e:  # optional sections may not exist yet
-            print(f"# {modname}: unavailable ({type(e).__name__}: {e})", file=sys.stderr)
-            continue
+        mod = importlib.import_module(modname)
         for fn in getattr(mod, "ALL", []):
             if only and only not in fn.__name__:
                 continue

@@ -82,7 +82,7 @@ def flow_choices_jnp(src: str, dst: str, line_addrs, num_paths: int):
     """Traced twin of :func:`flow_choices` (``jnp.uint64`` arithmetic wraps
     mod 2^64 exactly like numpy), so route-choice columns for traces that
     are *synthesized on-device* (``repro.data.workloads``) never leave the
-    accelerator.  Requires x64 (run under the ``enable_x64()`` scope every
+    accelerator.  Requires x64 (run under the ``jax.enable_x64(True)`` scope every
     replay engine already opens); bit-equal to the scalar and numpy twins
     (property-tested)."""
     import jax.numpy as jnp

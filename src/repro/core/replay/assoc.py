@@ -47,12 +47,13 @@ constraint.
 
 from __future__ import annotations
 
+import functools
 from typing import Dict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.core.replay.engine import ReplayResult
 from repro.core.replay.spec import (
@@ -86,6 +87,40 @@ def _affine_max(left, right):
     return a1 + a2, jnp.maximum(b1 + a2, b2)
 
 
+SCAN_ROW = 8192
+
+
+def assoc_scan(fn, elems, identity, reverse=False):
+    """:func:`jax.lax.associative_scan` over 1-D leaves, computed as a
+    sequential ``lax.scan`` across ``SCAN_ROW``-element rows with a
+    log-depth scan inside each row.
+
+    Same values: ``fn`` is associative and the integer arithmetic exact;
+    ``identity``, shaped like ``elems`` with one scalar per leaf, is its
+    neutral element and pads the last row.  The TPU compiler takes minutes
+    over one trace-long 1-D scan at 2^20 elements, and under a second over
+    rows.  ``reverse`` needs a commutative ``fn``."""
+    leaves, tree = jax.tree.flatten(elems)
+    ids = jax.tree.leaves(identity)
+    n = leaves[0].shape[0]
+    if n <= SCAN_ROW:
+        return jax.lax.associative_scan(fn, elems, reverse=reverse)
+    pad = (-n) % SCAN_ROW
+    rows = tree.unflatten([
+        jnp.concatenate([x, jnp.full(pad, e, x.dtype)]).reshape(-1, SCAN_ROW)
+        for x, e in zip(leaves, ids)])
+    end = 0 if reverse else -1
+
+    def body(carry, r):
+        out = fn(carry, jax.lax.associative_scan(fn, r, reverse=reverse))
+        return jax.tree.map(lambda o: o[end], out), out
+
+    init = tree.unflatten([jnp.asarray(e, x.dtype)
+                           for x, e in zip(leaves, ids)])
+    _, out = jax.lax.scan(body, init, rows, reverse=reverse)
+    return jax.tree.map(lambda o: o.reshape(-1)[:n], out)
+
+
 def busy_until(arrivals, services, active=None, init=None):
     """Associative form of the sequential busy-until fold.
 
@@ -101,8 +136,8 @@ def busy_until(arrivals, services, active=None, init=None):
     ``services`` may vary per element (QoS-weighted paces, mixed transfer
     sizes); ``active`` gates elements that bypass the chain (e.g. cache
     hits on a fill path).  Returns the chain value right after each
-    element, exactly equal to the fold (property-tested).  Log depth via
-    :func:`jax.lax.associative_scan`.
+    element, exactly equal to the fold (property-tested).  Log depth per
+    row of :func:`assoc_scan`.
     """
     arrivals = jnp.asarray(arrivals)
     services = jnp.asarray(services)
@@ -115,7 +150,7 @@ def busy_until(arrivals, services, active=None, init=None):
     else:
         a = jnp.where(active, services, 0)
         b = jnp.where(active, arrivals + services, neg)
-    cum_a, cum_b = jax.lax.associative_scan(_affine_max, (a, b))
+    cum_a, cum_b = assoc_scan(_affine_max, (a, b), (0, neg))
     return jnp.maximum(init + cum_a, cum_b)
 
 
@@ -188,6 +223,10 @@ class _NumpyOps:
     xp = np
 
     @staticmethod
+    def run(stage, cfg, *args, **static):
+        return stage(_NumpyOps, cfg, *args, **static)
+
+    @staticmethod
     def cummax(x):
         return np.maximum.accumulate(x)
 
@@ -201,38 +240,47 @@ class _NumpyOps:
 
 
 class _JnpOps:
-    """Accelerator backend: the same passes as eager jnp ops (few enough
-    per sweep that dispatch overhead is irrelevant), with the sorted
-    completion stream from the bounded-displacement block sort."""
+    """Accelerator backend: the same passes as jnp ops, each stage one
+    jitted program (op-by-op dispatch would compile every op and shape
+    separately on the accelerator), with the sorted completion stream from
+    the bounded-displacement block sort."""
 
     xp = jnp
 
     @staticmethod
+    def run(stage, cfg, *args, **static):
+        return _jitted(stage, tuple(static))(_JnpOps, cfg, *args, **static)
+
+    # assoc_scan, not lax.cummax/cummin: the TPU lowers those to a
+    # trace-wide reduce_window, which takes minutes to compile on int64
+    @staticmethod
     def cummax(x):
-        return jax.lax.cummax(x)
+        return assoc_scan(jnp.maximum, x, jnp.iinfo(x.dtype).min)
 
     @staticmethod
     def rcummin(x):
-        return jax.lax.cummin(x, reverse=True)
+        return assoc_scan(jnp.minimum, x, jnp.iinfo(x.dtype).max,
+                          reverse=True)
 
     @staticmethod
     def sort(x, sort_block):
         return _local_sort(x, sort_block)
 
 
-def _solve_core(ops, cfg: StackConfig, p: Dict, addrs, writes, start_tick,
-                max_sweeps: int, sort_block: int):
-    """The certified Kleene solve, written once against a tiny ops shim so
-    the numpy (CPU) and jnp (accelerator) backends share every formula.
-    Returns ``(issues, dones, hit_flags, sweeps, certified)``."""
-    xp = ops.xp
-    n = int(addrs.shape[0])
-    depth = cfg.outstanding
-    start = int(start_tick)
-    ar = xp.arange(n, dtype=xp.int64)
-    ov = p["issue_ov"]
+@functools.lru_cache(maxsize=None)
+def _jitted(stage, static_names):
+    return jax.jit(stage, static_argnums=(0, 1),
+                   static_argnames=static_names)
 
-    # ---- elementwise media data: return-path tails + hit flags
+
+# Solver stages, each ``stage(ops, cfg, ...)``: written once against the
+# ops shim so the numpy (CPU) and jnp (accelerator) backends share every
+# formula.
+def _setup(ops, cfg: StackConfig, p: Dict, addrs, writes, start):
+    """Elementwise media data (return-path tails + hit flags) and the
+    first issue ticks, paced from ``start`` with no LFB floor."""
+    xp = ops.xp
+    n = addrs.shape[0]
     posted = writes if cfg.posted_writes else xp.zeros(n, bool)
     if cfg.kind == DRAM:
         tail = xp.where(posted, p["pack"], p["load"])
@@ -242,38 +290,67 @@ def _solve_core(ops, cfg: StackConfig, p: Dict, addrs, writes, start_tick,
         hit = xp.concatenate([xp.zeros(1, bool), row[1:] == row[:-1]])
         lat = p["lat"][xp.where(writes, 1, 0), xp.where(hit, 1, 0)]
         tail = xp.where(posted, p["pack"], lat)
+    u = _pacing(ops, p, xp.full(n, start, xp.int64),
+                xp.arange(n, dtype=xp.int64))
+    return tail, hit, u
 
-    def stage(arr, svc):
-        # constant-service busy-until chain seeded at 0 (fresh port/media)
-        return xp.maximum(ops.cummax(arr - svc * ar), 0) + svc * (ar + 1)
 
-    def forward(u):
-        """Issue ticks -> completion ticks: the tandem of transport-hop and
-        media busy-until chains, mirrored stage for stage."""
-        t = u
-        for h in range(cfg.num_hops):
-            t = stage(t, p["hop_occ"][h]) + p["hop_after"][h]
-        t = t + p["rt_extra"]
-        return stage(t, p["occ"]) + tail
+def _stage(ops, arr, svc, ar):
+    # constant-service busy-until chain seeded at 0 (fresh port/media)
+    return ops.xp.maximum(ops.cummax(arr - svc * ar), 0) + svc * (ar + 1)
 
-    def pacing(floor):
-        return ops.cummax(floor - ov * ar) + ov * ar
 
-    floor0 = xp.full(n, start, xp.int64)
-    floor, sorted_ok = floor0, True
-    u = pacing(floor0)
-    dones = floor0
+def _forward(ops, cfg: StackConfig, p: Dict, u, tail, ar):
+    """Issue ticks -> completion ticks: the tandem of transport-hop and
+    media busy-until chains, mirrored stage for stage."""
+    t = u
+    for h in range(cfg.num_hops):
+        t = _stage(ops, t, p["hop_occ"][h], ar) + p["hop_after"][h]
+    t = t + p["rt_extra"]
+    return _stage(ops, t, p["occ"], ar) + tail
+
+
+def _pacing(ops, p: Dict, floor, ar):
+    return ops.cummax(floor - p["issue_ov"] * ar) + p["issue_ov"] * ar
+
+
+def _sweep(ops, cfg: StackConfig, p: Dict, u, tail, start, *,
+           sort_block: int):
+    """One Kleene sweep: the completions of issue ticks ``u``, the popped
+    floor they imply, and the re-paced issue ticks."""
+    xp = ops.xp
+    n = u.shape[0]
+    depth = cfg.outstanding
+    ar = xp.arange(n, dtype=xp.int64)
+    dones = _forward(ops, cfg, p, u, tail, ar)
+    floor, sorted_ok = xp.full(n, start, xp.int64), True
+    if n > depth:
+        srt = ops.sort(dones, sort_block)
+        sorted_ok = (srt[1:] >= srt[:-1]).all()
+        floor = xp.where(ar < depth, start,
+                         srt[xp.clip(ar - depth, 0, n - 1)])
+    u2 = _pacing(ops, p, floor, ar)
+    return dones, floor, sorted_ok, u2, (u2 == u).all()
+
+
+def _suffix_ok(ops, cfg: StackConfig, dones, floor):
+    return (ops.rcummin(dones) > floor).all()
+
+
+def _solve_core(ops, cfg: StackConfig, p: Dict, addrs, writes, start_tick,
+                max_sweeps: int, sort_block: int):
+    """The certified Kleene solve.  Returns ``(issues, dones, hit_flags,
+    sweeps, certified)``."""
+    start = int(start_tick)
+    tail, hit, u = ops.run(_setup, cfg, p, addrs, writes, start)
+    dones = floor = u
+    sorted_ok = True
     converged = False
     sweeps = 0
     for sweeps in range(1, max_sweeps + 1):
-        dones = forward(u)
-        if n > depth:
-            srt = ops.sort(dones, sort_block)
-            sorted_ok = bool((srt[1:] >= srt[:-1]).all())
-            floor = xp.where(ar < depth, start,
-                             srt[xp.clip(ar - depth, 0, n - 1)])
-        u2 = pacing(floor)
-        if bool((u2 == u).all()):
+        dones, floor, sorted_ok, u2, same = ops.run(
+            _sweep, cfg, p, u, tail, start, sort_block=sort_block)
+        if bool(same):
             converged = True
             break
         u = u2
@@ -284,9 +361,8 @@ def _solve_core(ops, cfg: StackConfig, p: Dict, addrs, writes, start_tick,
     # in the block sort surfaces here); and the strict suffix property
     # proves the sorted-pop identity was valid at every index — together
     # the candidate solves the causal recurrence, whose solution is unique.
-    suffmin = ops.rcummin(dones)
-    certified = (converged and sorted_ok
-                 and bool((suffmin > floor).all()))
+    certified = (converged and bool(sorted_ok)
+                 and bool(ops.run(_suffix_ok, cfg, dones, floor)))
     return (np.asarray(u), np.asarray(dones), np.asarray(hit), sweeps,
             certified)
 
@@ -393,7 +469,7 @@ class AssocReplayEngine:
                 _NumpyOps, cfg, params, addrs, writes, start_tick,
                 self.max_sweeps, sort_block)
         else:
-            with enable_x64():
+            with enable_x64(True):
                 pj = jax.tree.map(jnp.asarray, params)
                 issues, dones, hits, sweeps, certified = _solve_core(
                     _JnpOps, cfg, pj, jnp.asarray(addrs),

@@ -53,7 +53,7 @@ thunks, so the step is written to minimize thunks and buffer copies):
   uses port *h* on a single-host route), fusing into neighboring
   elementwise work.
 
-Tick arithmetic runs in int64 under :func:`jax.experimental.enable_x64`
+Tick arithmetic runs in int64 under :func:`jax.enable_x64`
 (scoped — the rest of the process keeps JAX's default 32-bit types; the
 golden suite also runs under ambient ``JAX_ENABLE_X64=1`` in CI to guard
 both entry modes); at 1 tick = 1 ps, int32 would overflow after 2.1 ms of
@@ -70,7 +70,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.core.fabric.fabric import FabricAttachedDevice
 from repro.core.replay import stack
@@ -842,7 +842,7 @@ class ReplayEngine:
         if plan is not None and plan.has_poison:
             poisoned = plan.poisoned_np(
                 0, np.arange(addrs.size, dtype=np.int64), writes)
-        with enable_x64():
+        with enable_x64(True):
             pj = jax.tree.map(jnp.asarray, params)
             if cfg.num_routes > 1:
                 from repro.core.replay.spec import access_route_choices
@@ -1027,7 +1027,7 @@ class ReplayEngine:
             def cb(seen, carry, parts):
                 on_chunk(seen, lambda: _snapshot(seen, carry, parts))
 
-        with enable_x64():
+        with enable_x64(True):
             pj = jax.tree.map(jnp.asarray, params)
             carry0 = None
             if resume_state is not None:

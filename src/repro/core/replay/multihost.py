@@ -62,7 +62,7 @@ from typing import Dict, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.core.devices import (CXLDRAMDevice, DRAMDevice, NullLink,
                                 POSTED_ACK_NS)
@@ -938,7 +938,7 @@ class MultiHostReplay:
                 "QoS replay needs start_tick >= 0 (the virtual-clock and "
                 "arrival sentinels assume non-negative ticks)")
         mspec = self.metrics
-        with enable_x64():
+        with enable_x64(True):
             who, issues, dones, bad, gcs, aux = self._dispatch(
                 cfg, params, devs, addrs, writes, lens, start_tick,
                 mspec, want_lat, size, chunk_size)

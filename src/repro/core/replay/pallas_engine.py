@@ -75,8 +75,9 @@ def run_pallas(device, addrs: np.ndarray, writes: np.ndarray, *,
     """Replay (addrs, writes) through the fused Pallas kernel; returns a
     :class:`~repro.core.replay.engine.ReplayResult`.
 
-    ``interpret=None`` auto-detects: the real kernel on a TPU backend,
-    op-level interpret emulation elsewhere (CPU/GPU).
+    ``interpret=None`` picks by backend: the compiled kernel on a TPU,
+    interpret mode on the CPU, and an error anywhere else
+    (:func:`repro.kernels.ops.interpret_mode`).
 
     ``validate=True`` recomputes the latency stream from the kernel's own
     decisions + arrivals through the associative busy-until formulation
@@ -85,13 +86,12 @@ def run_pallas(device, addrs: np.ndarray, writes: np.ndarray, *,
     two disagree bit-for-bit — a cheap end-to-end cross-check of the
     in-kernel sequential chain, run on every golden-trace conformance
     pass."""
-    import jax
-
     from repro.core.replay.engine import ReplayResult
     from repro.kernels.cache_sim import cache_sim_fused
+    from repro.kernels.ops import interpret_mode
 
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     plan = getattr(device, "fault_plan", None)
     if plan is None:
         plan = getattr(getattr(device, "fabric", None), "fault_plan", None)

@@ -5,11 +5,13 @@ axis.
 fused multi-host scan — the per-host LFB slots / clocks / trace cursors,
 the :mod:`repro.core.replay.stack` media and (private) flash lanes, and the
 per-host trace / route / fault columns — across ``D`` JAX devices with
-``jax.experimental.shard_map``, so an ``H``-host replay holds ``~H/D``
-per-device state.  The *shared* simulator state stays explicitly
-replicated: the per-port busy-until vector, the QoS virtual-finish /
-last-arrival tables and the global stamp counter are updated identically on
-every shard from broadcast winner inputs, so replicas never diverge.
+``jax.shard_map``, so an ``H``-host replay holds ``~H/D`` per-device
+state (inputs included: the host-sharded columns are placed shard by
+shard, never whole on one device).  The *shared* simulator state stays
+explicitly replicated: the per-port busy-until vector, the QoS
+virtual-finish / last-arrival tables and the global stamp counter are
+updated identically on every shard from broadcast winner inputs, so
+replicas never diverge.
 
 Two collectives per scan step mirror the global issue order exactly:
 
@@ -62,8 +64,8 @@ from typing import Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core.fabric.switch import ACTIVE_WINDOW_OCC
@@ -310,8 +312,8 @@ def _build_runner(cfg: MultiCfg, devices: Tuple, block: int, mspec,
     D = len(devices)
     body = functools.partial(_body, cfg, D, mspec, want_lat, size, block)
     f = shard_map(body, mesh=mesh, in_specs=(P(), P("hosts"), P()),
-                  out_specs=P(), check_rep=False)
-    return jax.jit(f)
+                  out_specs=P(), check_vma=False)
+    return jax.jit(f), mesh
 
 
 class ShardedMultiHostReplay(MultiHostReplay):
@@ -383,8 +385,10 @@ class ShardedMultiHostReplay(MultiHostReplay):
         mesh_devs = tuple(devices[:D])
         self.last_mesh = {"device_count": D, "hosts_per_device": H // D}
         sh, rep = self._shard_tensors(cfg, params, lens, addrs, writes)
-        run = _build_runner(cfg, mesh_devs, self.block_size, mspec,
-                            want_lat, size)
-        sh = jax.tree.map(jnp.asarray, sh)
-        rep = jax.tree.map(jnp.asarray, rep)
+        run, mesh = _build_runner(cfg, mesh_devs, self.block_size, mspec,
+                                  want_lat, size)
+        # place each shard on its own device (never the whole host axis
+        # on one) and the shared tensors on every device
+        sh = jax.device_put(sh, NamedSharding(mesh, P("hosts")))
+        rep = jax.device_put(rep, NamedSharding(mesh, P()))
         return run(_i64(start_tick), sh, rep)

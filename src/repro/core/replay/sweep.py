@@ -36,7 +36,7 @@ from typing import Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.core.replay import stack
 from repro.core.replay.engine import _scan_stack
@@ -144,7 +144,7 @@ def cache_design_sweep(device, addrs, writes, *,
         batched.add(name)
 
     trace_ax = 0 if addrs.ndim == 2 else None
-    with enable_x64():
+    with enable_x64(True):
         pj = {k: jnp.asarray(v) for k, v in params.items()}
         issues, dones, flags, final, _ = _run_cache_lanes(
             cfg, pj, (jnp.asarray(addrs), jnp.asarray(writes)),
@@ -213,7 +213,7 @@ def host_count_sweep(targets: Sequence, traces: Sequence,
                                      devices=devices)
         cfg, params, devs, addrs, writes, lens, size = eng.prepare(traces)
         out: List[MultiHostResult] = []
-        with enable_x64():
+        with enable_x64(True):
             for h in host_counts:
                 lane = np.where(np.arange(lens.size) < h, lens, 0)
                 who, issues, dones, bad, _, _ = eng._dispatch(
@@ -240,7 +240,7 @@ def host_count_sweep(targets: Sequence, traces: Sequence,
                      "hosts_per_device": int(lens.size)})
     lane_lens = np.stack([
         np.where(np.arange(lens.size) < h, lens, 0) for h in host_counts])
-    with enable_x64():
+    with enable_x64(True):
         pj = jax.tree.map(jnp.asarray, params)
         who, issues, dones, bad, _, _ = _run_multi_lanes(
             cfg, pj, jnp.asarray(devs), jnp.asarray(addrs),
@@ -317,7 +317,7 @@ def fault_seed_sweep(make_targets, traces: Sequence, seeds: Sequence[int],
     pj = dict(base)
     for k in _FAULT_KEYS:
         pj[k] = np.stack(stacked[k])
-    with enable_x64():
+    with enable_x64(True):
         pj = jax.tree.map(jnp.asarray, pj)
         who, issues, dones, bad, _, _ = _run_fault_lanes(
             cfg0, pj, jnp.asarray(devs), jnp.asarray(addrs),

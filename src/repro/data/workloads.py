@@ -31,7 +31,7 @@ Writes are an independent hash coin against ``write_frac`` and the
 sub-page line offset is a third hash stream, so two kinds sharing a seed
 still draw independent decisions (per-stream salts, like the fault
 classes).  All twins run their integer arithmetic mod 2^64; the jnp twin
-needs x64 (use the ``enable_x64()`` scope every replay engine already
+needs x64 (use the ``jax.enable_x64(True)`` scope every replay engine already
 opens, or call inside one).
 """
 

@@ -20,7 +20,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
@@ -126,12 +126,12 @@ def decode_attn_sharded(x, blk, cfg: ArchConfig, k_cache, v_cache, cur, ctx,
             body, mesh=mesh,
             in_specs=base_in + (P(b, tp, None), P(b, tp, None)),
             out_specs=base_out + (P(b, tp, None), P(b, tp, None)),
-            check_rep=False,
+            check_vma=False,
         )(x, blk["wq"], blk["wk"], blk["wv"], blk["wo"], k_cache, v_cache,
           cur, k_scale, v_scale)
     return shard_map(
         body, mesh=mesh,
         in_specs=base_in,
         out_specs=base_out,
-        check_rep=False,
+        check_vma=False,
     )(x, blk["wq"], blk["wk"], blk["wv"], blk["wo"], k_cache, v_cache, cur)

@@ -20,7 +20,7 @@ from typing import Any, Callable, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -89,7 +89,7 @@ def pipeline_forward(block_fn: Callable, mesh, *, stage_axis: str = "pod",
             in_specs=(P(), jax.tree.map(lambda _: P(stage_axis),
                                         stacked_blocks)),
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )(x, stacked_blocks)
 
     return fn

@@ -1,7 +1,8 @@
 """jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True on CPU (kernel bodies execute in Python for
-validation) and False on TPU, where the kernels compile to Mosaic.
+``interpret`` is True on CPU (kernel bodies execute in Python for
+validation) and False on TPU, where the kernels compile to Mosaic; any
+other backend is refused (:func:`interpret_mode`).
 """
 
 from __future__ import annotations
@@ -14,24 +15,31 @@ from repro.kernels.flash_decode import combine_partials, flash_decode_tpu
 from repro.kernels.page_gather import page_gather, page_scatter
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+def interpret_mode() -> bool:
+    """Interpret on the CPU backend, compile on a TPU.  Any other backend
+    raises: a kernel is never interpreted on an accelerator in silence."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas kernels compile for 'tpu' and interpret on 'cpu'; "
+            f"backend {backend!r} has neither mode")
+    return backend == "cpu"
 
 
-def cache_sim_op(pages, writes, *, num_sets, ways, policy="lru", chunk=512):
+def cache_sim_op(pages, writes, *, num_sets, ways, policy="lru", chunk=1024):
     return cache_sim(pages, writes, num_sets=num_sets, ways=ways,
                      policy=policy, chunk=chunk,
-                     interpret=_interpret_default())
+                     interpret=interpret_mode())
 
 
 def flash_attention_op(q, k, v, *, causal=True, window=0, bq=128, bk=128):
     return flash_attention_tpu(q, k, v, causal=causal, window=window,
-                               bq=bq, bk=bk, interpret=_interpret_default())
+                               bq=bq, bk=bk, interpret=interpret_mode())
 
 
 def flash_decode_op(q, k_cache, v_cache, n_valid, *, bk=512):
     return flash_decode_tpu(q, k_cache, v_cache, n_valid, bk=bk,
-                            interpret=_interpret_default())
+                            interpret=interpret_mode())
 
 
 def _as3d(x):
@@ -48,7 +56,7 @@ def _as3d(x):
 
 def page_gather_op(pool, table):
     pool3, orig = _as3d(pool)
-    out = page_gather(pool3, table, interpret=_interpret_default())
+    out = page_gather(pool3, table, interpret=interpret_mode())
     if orig is not None:
         out = out.reshape((out.shape[0],) + orig[1:])
     return out
@@ -57,9 +65,10 @@ def page_gather_op(pool, table):
 def page_scatter_op(pool, table, pages):
     pool3, orig = _as3d(pool)
     pages3, _ = _as3d(pages)
-    out = page_scatter(pool3, table, pages3, interpret=_interpret_default())
+    out = page_scatter(pool3, table, pages3, interpret=interpret_mode())
     return out.reshape(orig) if orig is not None else out
 
 
-__all__ = ["cache_sim_op", "flash_attention_op", "flash_decode_op",
-           "combine_partials", "page_gather_op", "page_scatter_op"]
+__all__ = ["interpret_mode", "cache_sim_op", "flash_attention_op",
+           "flash_decode_op", "combine_partials", "page_gather_op",
+           "page_scatter_op"]

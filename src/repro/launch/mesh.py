@@ -9,6 +9,14 @@ initialization, and smoke tests/benches must keep seeing 1 device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """A mesh whose axes are all ``Auto``: the sharding rules here place
+    arrays with sharding constraints and let XLA propagate the rest, which
+    ``jax.make_mesh``'s default ``Explicit`` axes refuse for gathers."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -17,11 +25,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     optional pipeline parallelism over the pod axis (distributed/pipeline)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(n_data: int = 2, n_model: int = 2, n_pod: int = 0):
     """Small host-device mesh for tests (requires forced host device count)."""
     if n_pod:
-        return jax.make_mesh((n_pod, n_data, n_model), ("pod", "data", "model"))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+        return _auto_mesh((n_pod, n_data, n_model), ("pod", "data", "model"))
+    return _auto_mesh((n_data, n_model), ("data", "model"))

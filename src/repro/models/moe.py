@@ -23,7 +23,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.configs.base import MoEConfig
 
@@ -176,7 +176,7 @@ def moe_ffn_sharded(x, p: MoEParams, cfg: MoEConfig, mesh,
                       P(tp_axis, None, dp_axes or None),
                       P(tp_axis, dp_axes or None, None)),
             out_specs=(P(dp_spec, None, None), P()),
-            check_rep=False,
+            check_vma=False,
         )(x, p.router, p.w_gate, p.w_up, p.w_down)
 
     if cfg.sharding == "ep" and cfg.n_experts % n_tp == 0 and n_tp > 1:
@@ -214,7 +214,7 @@ def moe_ffn_sharded(x, p: MoEParams, cfg: MoEConfig, mesh,
             in_specs=(P(dp_spec, None, None), P(None, None),
                       e_spec, e_spec, P(tp_axis, None, None)),
             out_specs=(P(dp_spec, None, None), P()),
-            check_rep=False,
+            check_vma=False,
         )(x, p.router, p.w_gate, p.w_up, p.w_down)
 
     # 'tp' mode: experts replicated, d_expert sharded; psum partial outputs.
@@ -243,5 +243,5 @@ def moe_ffn_sharded(x, p: MoEParams, cfg: MoEConfig, mesh,
                   P(None, None, tp_axis), P(None, None, tp_axis),
                   P(None, tp_axis, None)),
         out_specs=(P(dp_spec, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p.router, p.w_gate, p.w_up, p.w_down)

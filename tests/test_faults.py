@@ -12,7 +12,7 @@ the interpreted drivers, or refuses with :class:`ReplayUnsupported`.
 
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from golden.scenarios import ServiceTap
 from repro.core.cache.dram_cache import DRAMCacheConfig
@@ -96,7 +96,7 @@ def test_nand_jnp_twins_agree():
                                  nand_read_retry_max=2,
                                  erase_fail_rate=0.4), seed=3)
     statics = plan.nand_statics()
-    with enable_x64():
+    with enable_x64(True):
         import jax.numpy as jnp
         for seq in range(200):
             assert plan.nand_read_retries(seq) == int(

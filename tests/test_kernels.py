@@ -27,7 +27,7 @@ class TestCacheSimKernel:
                             jnp.int32)
         writes = jnp.asarray(rng.random(n) < 0.4)
         h, e = cache_sim_op(pages, writes, num_sets=num_sets, ways=ways,
-                            policy=policy, chunk=256)
+                            policy=policy, chunk=1024)
         hr, er = ref.cache_sim_ref(pages, writes, num_sets=num_sets,
                                    ways=ways, policy=policy)
         np.testing.assert_array_equal(np.asarray(h), np.asarray(hr))
@@ -38,7 +38,7 @@ class TestCacheSimKernel:
         n = 777  # not a multiple of chunk
         pages = jnp.asarray(rng.integers(0, 256, size=n), jnp.int32)
         writes = jnp.asarray(rng.random(n) < 0.5)
-        h, e = cache_sim_op(pages, writes, num_sets=16, ways=4, chunk=256)
+        h, e = cache_sim_op(pages, writes, num_sets=16, ways=4, chunk=1024)
         hr, er = ref.cache_sim_ref(pages, writes, num_sets=16, ways=4)
         np.testing.assert_array_equal(np.asarray(h), np.asarray(hr))
 

@@ -229,14 +229,14 @@ def test_percentiles_match_numpy_inverted_cdf():
 
 
 def test_bucket_index_numpy_jnp_twins_agree():
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     vals = np.concatenate([
         np.arange(0, 64),
         2 ** np.arange(3, 52, dtype=np.int64),
         2 ** np.arange(3, 52, dtype=np.int64) - 1,
         np.random.default_rng(5).integers(0, 1 << 52, 500)])
-    with enable_x64():
+    with enable_x64(True):
         jidx = np.asarray(bucket_index_jnp(vals, MAX_HIST_BUCKETS))
     nidx = bucket_index(vals, MAX_HIST_BUCKETS)
     assert (jidx == nidx).all()

@@ -409,12 +409,12 @@ def test_local_sort_equals_full_sort_for_bounded_displacement():
     """The accelerator path's two-pass block sort: exact on any stream
     whose elements sit within block//2 of their sorted slot (the
     completion-stream shape: monotone chain + bounded tails)."""
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     from repro.core.replay.assoc import _local_sort
 
     rng = np.random.default_rng(7)
-    with enable_x64():
+    with enable_x64(True):
         for _ in range(20):
             n = int(rng.integers(5, 700))
             occ = int(rng.integers(1, 40))
@@ -760,10 +760,10 @@ def _random_transport_case(seed, n=257):
 
 @pytest.mark.parametrize("seed", [0, 1, 2, 3])
 def test_assoc_busy_until_matches_sequential_fold(seed):
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     arr, svc, act, _ = _random_transport_case(seed)
-    with enable_x64():
+    with enable_x64(True):
         got = np.asarray(busy_until(arr, svc, active=act, init=0))
         ungated = np.asarray(busy_until(arr, svc))
     assert (got == _busy_fold(arr, svc, act, 0)).all()
@@ -772,15 +772,52 @@ def test_assoc_busy_until_matches_sequential_fold(seed):
     assert (ungated == ref).all()
 
 
+
+@pytest.mark.parametrize("rows", [2, 2.5])
+def test_assoc_scan_rows_equal_one_scan(rows):
+    """Row-wise ``assoc_scan`` (the form the TPU compiles quickly) equals
+    one trace-long associative scan: forward on the affine-max pair,
+    padded or not, and reverse on a commutative min."""
+    import jax
+    import jax.numpy as jnp
+    from jax import enable_x64
+
+    from repro.core.replay.assoc import SCAN_ROW, _affine_max, _neg, assoc_scan
+
+    arr, svc, act, _ = _random_transport_case(7, n=int(rows * SCAN_ROW))
+    with enable_x64(True):
+        neg = _neg(jnp.int64)
+        a = jnp.where(act, svc, 0)
+        b = jnp.where(act, arr + svc, neg)
+        got = assoc_scan(_affine_max, (a, b), (0, neg))
+        want = jax.lax.associative_scan(_affine_max, (a, b))
+        big = jnp.iinfo(jnp.int64).max
+        rmin = assoc_scan(jnp.minimum, jnp.asarray(arr), big, reverse=True)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    np.testing.assert_array_equal(np.asarray(rmin),
+                                  np.minimum.accumulate(arr[::-1])[::-1])
+
+
+def test_assoc_busy_until_longer_than_a_row_matches_fold():
+    from jax import enable_x64
+
+    from repro.core.replay.assoc import SCAN_ROW
+
+    arr, svc, act, _ = _random_transport_case(5, n=2 * SCAN_ROW + 3)
+    with enable_x64(True):
+        got = np.asarray(busy_until(arr, svc, active=act, init=0))
+    assert (got == _busy_fold(arr, svc, act, 0)).all()
+
 @pytest.mark.parametrize("seed", [0, 1, 2, 3])
 def test_assoc_port_busy_until_matches_sequential_fold(seed):
     """ECMP route-choice case: each access occupies one of P interleaved
     port chains; the one-hot affine-max scan must equal the per-port
     fold."""
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     arr, svc, _, ports = _random_transport_case(seed)
-    with enable_x64():
+    with enable_x64(True):
         got = np.asarray(port_busy_until(arr, svc, ports, 5, init=0))
     ref = _port_fold(arr, svc, ports, 5, 0)
     assert (got == ref).all()
@@ -935,13 +972,13 @@ if HAVE_HYPOTHESIS:
         sequences — including QoS-weighted paces (service = occ * W/w, the
         virtual-finish-time update) and ECMP route choices (per-access
         port selection)."""
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         arr = np.asarray(arr, np.int64)
         paced = np.asarray(svc, np.int64) * np.asarray(weights, np.int64)
         act = np.asarray(act)
         ports = np.asarray(ports)
-        with enable_x64():
+        with enable_x64(True):
             gated = np.asarray(busy_until(arr, paced, active=act, init=0))
             perport = np.asarray(port_busy_until(arr, paced, ports, 4,
                                                  init=0))

@@ -6,7 +6,7 @@ bursty duty cycle, scan periodicity)."""
 
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.data import (
     WORKLOAD_KINDS,
@@ -53,7 +53,7 @@ def test_scalar_numpy_jnp_twins_bit_equal(kind):
     for i in range(0, n, 37):
         a, w = access_at(spec, 11, 2, i)
         assert (a, w) == (int(an[i]), bool(wn[i]))
-    with enable_x64():
+    with enable_x64(True):
         aj, wj = host_trace_jnp(spec, 11, 2, n)
         assert np.array_equal(np.asarray(aj), an)
         assert np.array_equal(np.asarray(wj), wn)
