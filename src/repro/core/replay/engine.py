@@ -71,6 +71,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import enable_x64
+from jax.profiler import TraceAnnotation, annotate_function
 
 from repro.core.fabric.fabric import FabricAttachedDevice
 from repro.core.replay import stack
@@ -85,6 +86,7 @@ from repro.core.replay.spec import (
 # importers take them from repro.core.replay.stack directly.
 from repro.core.replay.stack import BIG, MAX_ACCESSES, _i64
 from repro.core.workloads.driver import TraceResult
+from repro.obs import scopes
 
 
 def _qos_mask(cfg: StackConfig):
@@ -97,6 +99,7 @@ def _qos_mask(cfg: StackConfig):
 
 
 # ---------------------------------------------------------------- transport
+@scopes.scoped("transport")
 def _transport(cfg: StackConfig, p: Dict, pb: Tuple, t, qacc=None, qthr=None):
     """Routed store-and-forward transport: the vectorized form of
     :meth:`SwitchPort.transmit` along the precomputed route (hop *h* is
@@ -133,6 +136,7 @@ def _transport(cfg: StackConfig, p: Dict, pb: Tuple, t, qacc=None, qthr=None):
             tuple(qt) if qt is not None else None)
 
 
+@scopes.scoped("transport")
 def _transport_cols(cfg: StackConfig, p: Dict, pb, t, cols, qacc=None,
                     vft=None, qthr=None):
     """Fault-lane transport: each access carries its own hop columns
@@ -171,6 +175,7 @@ def _transport_cols(cfg: StackConfig, p: Dict, pb, t, cols, qacc=None,
     return pb, t + p["rt_extra"], qacc, vft, qthr
 
 
+@scopes.scoped("transport")
 def _transport_ecmp(cfg: StackConfig, p: Dict, pb, t, route, qacc=None,
                     qthr=None):
     """ECMP transport: hop *h* of the chosen route occupies the port
@@ -458,8 +463,9 @@ def _scan_chunk(cfg: StackConfig, p: Dict, carry, xs: Dict, block=1,
     def step(carry, x):
         slots, now, ctr, pb, st, aux = carry
         addr, wr = x["addr"], x["wr"]
-        k = jnp.argmin(slots)
-        issue = jnp.maximum(now, slots[k])
+        with jax.named_scope("lfb"):
+            k = jnp.argmin(slots)
+            issue = jnp.maximum(now, slots[k])
         posted = wr if cfg.posted_writes else jnp.zeros((), bool)
         qacc = aux.get("q")
         qthr = aux.get("qthr")
@@ -477,32 +483,35 @@ def _scan_chunk(cfg: StackConfig, p: Dict, carry, xs: Dict, block=1,
             lane=0, flash_lane=0, t=t, addr=addr, write=wr, posted=posted,
             ctr=ctr))
         done = out["done"]
-        if mspec is not None:
-            from repro.core.replay import metrics as _metrics
-            aux = {**aux, "q": qacc}
-            if qthr is not None:
-                aux["qthr"] = qthr
-            if vft is not None:
-                aux["vft"] = vft
-            if "acc" in aux:
-                aux["med"] = aux["med"] + _metrics.media_increments(
-                    cfg.kind, wr, out)
-                aux["acc"] = _metrics.acc_update(
-                    mspec, aux["acc"], host=0, dev=0, n_hosts=1,
-                    n_devs=1, issue=issue, done=done, size=size,
-                    hit=out["hit"])
-        if not want_lat:
-            aux = {**aux,
-                   "first": jnp.minimum(aux["first"], issue),
-                   "last": jnp.maximum(aux["last"], done),
-                   "sum": aux["sum"] + (done - issue)}
-        flags = jnp.where(out["hit"], 1, 0) | jnp.where(out["evict"], 2, 0)
-        if mspec is not None and want_lat:
-            from repro.core.replay import metrics as _metrics
-            for bit, key in _metrics.FLAG_EVENT_BITS[cfg.kind]:
-                flags = flags | jnp.where(out[key], 1 << bit, 0)
-        new = (slots.at[k].set(done), issue + p["issue_ov"], ctr + 1, pb,
-               st, aux)
+        with jax.named_scope("telemetry"):
+            if mspec is not None:
+                from repro.core.replay import metrics as _metrics
+                aux = {**aux, "q": qacc}
+                if qthr is not None:
+                    aux["qthr"] = qthr
+                if vft is not None:
+                    aux["vft"] = vft
+                if "acc" in aux:
+                    aux["med"] = aux["med"] + _metrics.media_increments(
+                        cfg.kind, wr, out)
+                    aux["acc"] = _metrics.acc_update(
+                        mspec, aux["acc"], host=0, dev=0, n_hosts=1,
+                        n_devs=1, issue=issue, done=done, size=size,
+                        hit=out["hit"])
+            if not want_lat:
+                aux = {**aux,
+                       "first": jnp.minimum(aux["first"], issue),
+                       "last": jnp.maximum(aux["last"], done),
+                       "sum": aux["sum"] + (done - issue)}
+            flags = (jnp.where(out["hit"], 1, 0)
+                     | jnp.where(out["evict"], 2, 0))
+            if mspec is not None and want_lat:
+                from repro.core.replay import metrics as _metrics
+                for bit, key in _metrics.FLAG_EVENT_BITS[cfg.kind]:
+                    flags = flags | jnp.where(out[key], 1 << bit, 0)
+        with jax.named_scope("lfb"):
+            new = (slots.at[k].set(done), issue + p["issue_ov"], ctr + 1,
+                   pb, st, aux)
         if masked:
             v = x["valid"]
             new = jax.tree.map(lambda old, nxt: jnp.where(v, nxt, old),
@@ -693,8 +702,8 @@ def _chunked_scan(cfg: StackConfig, p: Dict, chunks, n: int, chunk: int,
             cols = {k: _pad_rows(v, chunk) for k, v in cols.items()}
             cols["valid"] = np.arange(chunk) < m
         xs = {k: jnp.asarray(v) for k, v in cols.items()}
-        carry, ys = _replay_chunk(cfg, p, _dealias(carry), xs, block, mspec,
-                                  want_lat, size)
+        carry, ys = scopes.run(_replay_chunk, cfg, p, _dealias(carry), xs,
+                               block, mspec, want_lat, size)
         if want_lat:
             iss, dn, fl = ys
             parts.append((np.asarray(iss[:m]), np.asarray(dn[:m]),
@@ -811,12 +820,13 @@ class ReplayEngine:
         mspec = self.metrics
         want_lat = bool(return_latencies)
         plan = self._active_plan()
-        cfg, params = build_stack(
-            self.device, size=size, outstanding=self.outstanding,
-            issue_overhead_ns=self.issue_overhead_ns,
-            posted_writes=self.posted_writes, n_accesses=addrs.size,
-            max_addr=int(addrs.max(initial=0)),
-            counters=mspec is not None)
+        with TraceAnnotation("replay.build"):
+            cfg, params = build_stack(
+                self.device, size=size, outstanding=self.outstanding,
+                issue_overhead_ns=self.issue_overhead_ns,
+                posted_writes=self.posted_writes, n_accesses=addrs.size,
+                max_addr=int(addrs.max(initial=0)),
+                counters=mspec is not None)
         routes = None
         fcols = None
         faulted = None
@@ -843,7 +853,8 @@ class ReplayEngine:
             poisoned = plan.poisoned_np(
                 0, np.arange(addrs.size, dtype=np.int64), writes)
         with enable_x64(True):
-            pj = jax.tree.map(jnp.asarray, params)
+            with TraceAnnotation("replay.put"):
+                pj = jax.tree.map(jnp.asarray, params)
             if cfg.num_routes > 1:
                 from repro.core.replay.spec import access_route_choices
                 routes = access_route_choices(self.device, addrs)
@@ -863,24 +874,31 @@ class ReplayEngine:
                             d["route"] = routes[lo:hi]
                         yield lo, hi, d
 
-                issues, dones, flags, final, aux = _chunked_scan(
-                    cfg, pj, _feed(), n, chunk, start_tick,
-                    self.block_size, mspec, want_lat, size)
-            elif cfg.fault_hops:
-                issues, dones, flags, final, aux = _run_stack_faulted(
-                    cfg, pj, jnp.asarray(addrs), jnp.asarray(writes),
-                    tuple(jnp.asarray(c) for c in fcols), _i64(start_tick),
-                    self.block_size, mspec, want_lat, size)
-            elif cfg.num_routes > 1:
-                issues, dones, flags, final, aux = _run_stack_ecmp(
-                    cfg, pj, jnp.asarray(addrs), jnp.asarray(writes),
-                    jnp.asarray(routes), _i64(start_tick), self.block_size,
-                    mspec, want_lat, size)
+                with TraceAnnotation("replay.run"):
+                    issues, dones, flags, final, aux = _chunked_scan(
+                        cfg, pj, _feed(), n, chunk, start_tick,
+                        self.block_size, mspec, want_lat, size)
             else:
-                issues, dones, flags, final, aux = _run_stack(
-                    cfg, pj, jnp.asarray(addrs), jnp.asarray(writes),
-                    _i64(start_tick), self.block_size, mspec, want_lat,
-                    size)
+                with TraceAnnotation("replay.put"):
+                    xs = [jnp.asarray(addrs), jnp.asarray(writes)]
+                    if cfg.fault_hops:
+                        runner = _run_stack_faulted
+                        xs.append(tuple(jnp.asarray(c) for c in fcols))
+                    elif cfg.num_routes > 1:
+                        runner = _run_stack_ecmp
+                        xs.append(jnp.asarray(routes))
+                    else:
+                        runner = _run_stack
+                    xs.append(_i64(start_tick))
+                with TraceAnnotation("replay.run"):
+                    issues, dones, flags, final, aux = scopes.run(
+                        runner, cfg, pj, *xs, self.block_size, mspec,
+                        want_lat, size)
+            if want_lat:
+                with TraceAnnotation("replay.fetch"):
+                    issues, dones, flags = (np.asarray(issues),
+                                            np.asarray(dones),
+                                            np.asarray(flags))
             return self._finish(
                 cfg, n=int(addrs.size), size=size, start_tick=start_tick,
                 want_lat=want_lat, issues=issues, dones=dones, flags=flags,
@@ -932,11 +950,12 @@ class ReplayEngine:
                 and isinstance(self.device, FabricAttachedDevice)):
             builder = _FaultColumnBuilder(self.device, plan, size, n,
                                           keep_flags=want_lat)
-        cfg, params = build_stack(
-            self.device, size=size, outstanding=self.outstanding,
-            issue_overhead_ns=self.issue_overhead_ns,
-            posted_writes=self.posted_writes, n_accesses=n,
-            max_addr=int(store.max_addr), counters=mspec is not None)
+        with TraceAnnotation("replay.build"):
+            cfg, params = build_stack(
+                self.device, size=size, outstanding=self.outstanding,
+                issue_overhead_ns=self.issue_overhead_ns,
+                posted_writes=self.posted_writes, n_accesses=n,
+                max_addr=int(store.max_addr), counters=mspec is not None)
         if builder is not None:
             qp = tuple(
                 i for i, key in enumerate(builder.port_keys)
@@ -1028,16 +1047,18 @@ class ReplayEngine:
                 on_chunk(seen, lambda: _snapshot(seen, carry, parts))
 
         with enable_x64(True):
-            pj = jax.tree.map(jnp.asarray, params)
-            carry0 = None
-            if resume_state is not None:
-                template = _init_carry(cfg, stack.init_state(cfg),
-                                       _i64(start_tick), mspec, want_lat)
-                carry0 = _restore_carry(template, resume_state["carry"])
-            issues, dones, flags, final, aux = _chunked_scan(
-                cfg, pj, _feed(), n, chunk, start_tick, self.block_size,
-                mspec, want_lat, size, carry=carry0, seen=seen0,
-                parts=parts0, on_chunk=cb)
+            with TraceAnnotation("replay.put"):
+                pj = jax.tree.map(jnp.asarray, params)
+                carry0 = None
+                if resume_state is not None:
+                    template = _init_carry(cfg, stack.init_state(cfg),
+                                           _i64(start_tick), mspec, want_lat)
+                    carry0 = _restore_carry(template, resume_state["carry"])
+            with TraceAnnotation("replay.run"):
+                issues, dones, flags, final, aux = _chunked_scan(
+                    cfg, pj, _feed(), n, chunk, start_tick, self.block_size,
+                    mspec, want_lat, size, carry=carry0, seen=seen0,
+                    parts=parts0, on_chunk=cb)
             poisoned = None
             if has_poison:
                 poisoned = (np.concatenate(poison_parts) if want_lat
@@ -1067,6 +1088,7 @@ class ReplayEngine:
     # shared post-processing: health check, poison bit, fault counters,
     # metrics bundle, result assembly (identical for one-shot / chunked /
     # store-streamed paths — called under enable_x64)
+    @functools.partial(annotate_function, name="replay.finish")
     def _finish(self, cfg, *, n, size, start_tick, want_lat, issues, dones,
                 flags, final, aux, plan, fstats, poisoned, faulted, writes,
                 addrs, routes, n_accesses=None, route_counts=None,

@@ -359,8 +359,11 @@ class MetricsBundle:
 
     def _force(self) -> None:
         if self._deferred is not None:
-            (self._hist, self._windows, self._dev_hist,
-             self._media) = self._deferred()
+            from jax.profiler import TraceAnnotation
+
+            with TraceAnnotation("metrics.fold"):
+                (self._hist, self._windows, self._dev_hist,
+                 self._media) = self._deferred()
             self._deferred = None
 
     @property

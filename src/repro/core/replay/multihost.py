@@ -63,6 +63,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import enable_x64
+from jax.profiler import TraceAnnotation, annotate_function
 
 from repro.core.devices import (CXLDRAMDevice, DRAMDevice, NullLink,
                                 POSTED_ACK_NS)
@@ -78,6 +79,7 @@ from repro.core.replay.spec import (DRAM, ReplayUnsupported, StackConfig,
                                     validate_trace_columns)
 from repro.core.replay.stack import MAX_ACCESSES, _i64
 from repro.core.workloads.driver import MultiHostResult, TraceResult
+from repro.obs import scopes
 
 BIG = 1 << 62
 # "never arrived" sentinel for the QoS last-arrival carry: far enough below
@@ -362,113 +364,125 @@ def _make_multi_step(cfg: MultiCfg, p: Dict, lens, lookup, mspec=None,
 
     def step(carry, _):
         slots, now, idx, port_busy, ctr, st, vft, last_arr, aux = carry
-        cand = jnp.where(idx < lens,
-                         jnp.maximum(now, jnp.min(slots, axis=1)), BIG)
-        i = jnp.argmin(cand)                 # ties -> lowest host index
-        valid = idx[i] < lens[i]             # padded steps are trailing
-        row = slots[i]
-        k = jnp.argmin(row)
-        issue = jnp.maximum(now[i], row[k])
+        with jax.named_scope("lfb"):
+            cand = jnp.where(idx < lens,
+                             jnp.maximum(now, jnp.min(slots, axis=1)), BIG)
+            i = jnp.argmin(cand)                 # ties -> lowest host index
+            valid = idx[i] < lens[i]             # padded steps are trailing
+            row = slots[i]
+            k = jnp.argmin(row)
+            issue = jnp.maximum(now[i], row[k])
         a, wr, dev, r, fc = lookup(i, idx[i])
         posted = wr if cfg.posted_writes else jnp.zeros((), bool)
-        t = issue
-        floor = _i64(0)
-        qacc = aux.get("q")
-        qthr = aux.get("qthr")
-        for h in range(cfg.max_hops):
-            if fc is not None:
-                on = fc["on"][h]
-                pi = fc["p"][h]
-                occ_h = fc["o"][h]      # retries charged: occ * (1 + r)
-                occ_c = fc["oc"][h]     # clean: the QoS entitlement
-                after_h = fc["a"][h]
+        with jax.named_scope("transport"):
+            t = issue
+            floor = _i64(0)
+            qacc = aux.get("q")
+            qthr = aux.get("qthr")
+            for h in range(cfg.max_hops):
+                if fc is not None:
+                    on = fc["on"][h]
+                    pi = fc["p"][h]
+                    occ_h = fc["o"][h]     # retries charged: occ * (1 + r)
+                    occ_c = fc["oc"][h]    # clean: the QoS entitlement
+                    after_h = fc["a"][h]
+                else:
+                    on = p["hop_on"][i, dev, r, h]
+                    pi = p["hop_port"][i, dev, r, h]
+                    occ_h = p["hop_occ"][i, dev, r, h]
+                    occ_c = occ_h
+                    after_h = p["hop_after"][i, dev, r, h]
+                if cfg.qos:
+                    # mirror of SwitchPort.qos_update at arrival tick t
+                    qon = on & p["qos_on"][pi]
+                    prev = vft[pi, i]
+                    win = occ_c * ACTIVE_WINDOW_OCC
+                    w_active = jnp.float64(0.0)
+                    # sorted-name order, like the dict walk
+                    for j in cfg.host_order:
+                        member = (j == i) | (last_arr[pi, j] + win > t)
+                        w_active = w_active + jnp.where(
+                            member, p["qos_w"][pi, j], 0.0)
+                    pace = (occ_c.astype(jnp.float64)
+                            * (w_active / p["qos_w"][pi, i])
+                            ).astype(jnp.int64)
+                    floor = jnp.maximum(
+                        floor, jnp.where(qon & (prev > t), prev + pace, 0))
+                    vft = vft.at[pi, i].set(
+                        jnp.where(qon, jnp.maximum(prev, t) + pace, prev))
+                    last_arr = last_arr.at[pi, i].set(
+                        jnp.where(qon, t, last_arr[pi, i]))
+                    if qthr is not None:
+                        # SwitchPort.qos_update's nonzero-floor return is the
+                        # python qos_throttle_events bump, hop for hop
+                        qthr = qthr.at[pi].add(
+                            jnp.where(qon & (prev > t) & valid, 1, 0))
+                start = jnp.maximum(t, port_busy[pi])
+                if qacc is not None:
+                    # SwitchPort.transmit: queued_ticks += start - now
+                    qacc = qacc.at[pi].add(
+                        jnp.where(on & valid, start - t, 0))
+                done_h = start + occ_h
+                port_busy = port_busy.at[pi].set(
+                    jnp.where(on, done_h, port_busy[pi]))
+                t = jnp.where(on, done_h + after_h, t)
+            t = t + p["rt_extra"]
+        with jax.named_scope("media"):
+            if cfg.stack.kind == DRAM:
+                # DRAM-class media keeps per-device timing arrays
+                # (heterogeneous pools); the stack step reads its scalar
+                # names
+                p_med = {"occ": p["dev_occ"][dev],
+                         "load": p["dev_load"][dev],
+                         "pack": p["dev_pack"][dev]}
             else:
-                on = p["hop_on"][i, dev, r, h]
-                pi = p["hop_port"][i, dev, r, h]
-                occ_h = p["hop_occ"][i, dev, r, h]
-                occ_c = occ_h
-                after_h = p["hop_after"][i, dev, r, h]
-            if cfg.qos:
-                # mirror of SwitchPort.qos_update at arrival tick t
-                qon = on & p["qos_on"][pi]
-                prev = vft[pi, i]
-                win = occ_c * ACTIVE_WINDOW_OCC
-                w_active = jnp.float64(0.0)
-                for j in cfg.host_order:   # sorted-name order, like dict walk
-                    member = (j == i) | (last_arr[pi, j] + win > t)
-                    w_active = w_active + jnp.where(member, p["qos_w"][pi, j],
-                                                    0.0)
-                pace = (occ_c.astype(jnp.float64)
-                        * (w_active / p["qos_w"][pi, i])).astype(jnp.int64)
-                floor = jnp.maximum(
-                    floor, jnp.where(qon & (prev > t), prev + pace, 0))
-                vft = vft.at[pi, i].set(
-                    jnp.where(qon, jnp.maximum(prev, t) + pace, prev))
-                last_arr = last_arr.at[pi, i].set(
-                    jnp.where(qon, t, last_arr[pi, i]))
-                if qthr is not None:
-                    # SwitchPort.qos_update's nonzero-floor return is the
-                    # python qos_throttle_events bump, hop for hop
-                    qthr = qthr.at[pi].add(
-                        jnp.where(qon & (prev > t) & valid, 1, 0))
-            start = jnp.maximum(t, port_busy[pi])
-            if qacc is not None:
-                # SwitchPort.transmit: queued_ticks += start - now
-                qacc = qacc.at[pi].add(jnp.where(on & valid, start - t, 0))
-            done_h = start + occ_h
-            port_busy = port_busy.at[pi].set(
-                jnp.where(on, done_h, port_busy[pi]))
-            t = jnp.where(on, done_h + after_h, t)
-        t = t + p["rt_extra"]
-        if cfg.stack.kind == DRAM:
-            # DRAM-class media keeps per-device timing arrays (heterogeneous
-            # pools); the stack step reads its scalar names
-            p_med = {"occ": p["dev_occ"][dev], "load": p["dev_load"][dev],
-                     "pack": p["dev_pack"][dev]}
-        else:
-            p_med = p
+                p_med = p
         st, out = stack.step(cfg.stack, p_med, st, dict(
             lane=dev, flash_lane=(p["flash_of"][dev] if cfg.n_flash else 0),
             t=t, addr=a, write=wr, posted=posted, ctr=ctr))
         done = out["done"]
         if cfg.qos:
-            done = jnp.maximum(done, floor)   # ack floor, data path untouched
-        bad, gcs = stack.flash_health(st)
-        if mspec is not None:
-            from repro.core.replay import metrics as _metrics
-            aux = {**aux,
-                   "acc": _metrics.acc_update(
-                       mspec, aux["acc"], host=i, dev=dev, n_hosts=H,
-                       n_devs=cfg.num_devs, issue=issue, done=done,
-                       size=size, hit=out["hit"], valid=valid),
-                   "med": aux["med"].at[dev].add(
-                       _metrics.media_increments(cfg.stack.kind, wr, out)
-                       * jnp.where(valid, 1, 0)),
-                   "q": qacc}
-            if qthr is not None:
-                aux = {**aux, "qthr": qthr}
-            if "flash" in aux:
-                aux = {**aux, "flash": jnp.where(
-                    valid, stack.flash_counters(st), aux["flash"])}
-            if "faults" in aux:
-                aux = {**aux, "faults": jnp.where(
-                    valid, jnp.stack(stack.fault_counters(st)),
-                    aux["faults"])}
-        if not want_lat:
-            neg = _i64(-BIG)
-            aux = {**aux,
-                   "first": aux["first"].at[i].min(
-                       jnp.where(valid, issue, BIG)),
-                   "last": aux["last"].at[i].max(
-                       jnp.where(valid, done, neg)),
-                   "sum": aux["sum"].at[i].add(
-                       jnp.where(valid, done - issue, 0)),
-                   "cnt": aux["cnt"].at[i].add(jnp.where(valid, 1, 0)),
-                   "bad": aux["bad"] | (bad & valid),
-                   "gcs": jnp.where(valid, gcs, aux["gcs"])}
-        slots = slots.at[i, k].set(done)
-        now = now.at[i].set(issue + p["issue_ov"])
-        idx = idx.at[i].set(idx[i] + 1)
+            with jax.named_scope("transport"):
+                # ack floor, data path untouched
+                done = jnp.maximum(done, floor)
+        with jax.named_scope("telemetry"):
+            bad, gcs = stack.flash_health(st)
+            if mspec is not None:
+                from repro.core.replay import metrics as _metrics
+                aux = {**aux,
+                       "acc": _metrics.acc_update(
+                           mspec, aux["acc"], host=i, dev=dev, n_hosts=H,
+                           n_devs=cfg.num_devs, issue=issue, done=done,
+                           size=size, hit=out["hit"], valid=valid),
+                       "med": aux["med"].at[dev].add(
+                           _metrics.media_increments(cfg.stack.kind, wr, out)
+                           * jnp.where(valid, 1, 0)),
+                       "q": qacc}
+                if qthr is not None:
+                    aux = {**aux, "qthr": qthr}
+                if "flash" in aux:
+                    aux = {**aux, "flash": jnp.where(
+                        valid, stack.flash_counters(st), aux["flash"])}
+                if "faults" in aux:
+                    aux = {**aux, "faults": jnp.where(
+                        valid, jnp.stack(stack.fault_counters(st)),
+                        aux["faults"])}
+            if not want_lat:
+                neg = _i64(-BIG)
+                aux = {**aux,
+                       "first": aux["first"].at[i].min(
+                           jnp.where(valid, issue, BIG)),
+                       "last": aux["last"].at[i].max(
+                           jnp.where(valid, done, neg)),
+                       "sum": aux["sum"].at[i].add(
+                           jnp.where(valid, done - issue, 0)),
+                       "cnt": aux["cnt"].at[i].add(jnp.where(valid, 1, 0)),
+                       "bad": aux["bad"] | (bad & valid),
+                       "gcs": jnp.where(valid, gcs, aux["gcs"])}
+        with jax.named_scope("lfb"):
+            slots = slots.at[i, k].set(done)
+            now = now.at[i].set(issue + p["issue_ov"])
+            idx = idx.at[i].set(idx[i] + 1)
         ys = (i, issue, done, bad, gcs) if want_lat else None
         return ((slots, now, idx, port_busy, ctr + 1, st, vft, last_arr,
                  aux), ys)
@@ -687,6 +701,7 @@ class MultiHostReplay:
             writes[i, :a.size] = w
         return self.prepare_arrays(addrs, writes, lens=lens, size=size)
 
+    @functools.partial(annotate_function, name="replay.build")
     def prepare_arrays(self, addrs, writes, *, lens=None, size: int = 64):
         """:meth:`prepare` for traces that already live as ``(H, L)``
         columns — on-device workload synthesis (:mod:`repro.data.workloads`)
@@ -782,6 +797,7 @@ class MultiHostReplay:
         return self._meta["deg_flags"], self._meta["fo_flags"]
 
     @staticmethod
+    @functools.partial(annotate_function, name="replay.finish")
     def aggregate(who, issues, dones, lens, size: int,
                   start_tick: int = 0) -> MultiHostResult:
         """Fold per-step (host, issue, done) streams into per-host results.
@@ -898,9 +914,9 @@ class MultiHostReplay:
                 wins["route"] = jnp.asarray(wr_)
             if wf is not None:
                 wins.update({k: jnp.asarray(v) for k, v in wf.items()})
-            carry, ys = _run_multi_chunk(
-                cfg, _dealias(carry), pj, wins, lj, jnp.asarray(base),
-                self.block_size, mspec, want_lat, size)
+            carry, ys = scopes.run(
+                _run_multi_chunk, cfg, _dealias(carry), pj, wins, lj,
+                jnp.asarray(base), self.block_size, mspec, want_lat, size)
             if want_lat:
                 parts.append(tuple(np.asarray(y) for y in ys))
         if want_lat:
@@ -924,11 +940,13 @@ class MultiHostReplay:
             return self._run_chunked(
                 cfg, params, devs, addrs, writes, lens, start_tick,
                 mspec, want_lat, size, int(chunk_size))
-        pj = jax.tree.map(jnp.asarray, params)
-        return _run_multi(
-            cfg, pj, jnp.asarray(devs), jnp.asarray(addrs),
-            jnp.asarray(writes), jnp.asarray(lens), _i64(start_tick),
-            self.block_size, mspec, want_lat, size)
+        with TraceAnnotation("replay.put"):
+            args = (cfg, jax.tree.map(jnp.asarray, params), jnp.asarray(devs),
+                    jnp.asarray(addrs), jnp.asarray(writes),
+                    jnp.asarray(lens), _i64(start_tick))
+        with TraceAnnotation("replay.run"):
+            return scopes.run(_run_multi, *args, self.block_size, mspec,
+                              want_lat, size)
 
     def _execute_prepared(self, prep, start_tick: int,
                           want_lat: bool = True, chunk_size=None):
@@ -943,8 +961,11 @@ class MultiHostReplay:
                 cfg, params, devs, addrs, writes, lens, start_tick,
                 mspec, want_lat, size, chunk_size)
             if want_lat:
-                bad = np.asarray(bad)
-                gcs = np.asarray(gcs)
+                with TraceAnnotation("replay.fetch"):
+                    bad = np.asarray(bad)
+                    gcs = np.asarray(gcs)
+                    who, issues, dones = (np.asarray(who), np.asarray(issues),
+                                          np.asarray(dones))
         # padded steps (beyond sum(lens)) replay past the end and may dirty
         # the sticky flash flags — judge health at the last *valid* step
         total = int(np.asarray(lens).sum())
@@ -981,9 +1002,6 @@ class MultiHostReplay:
                 aux.get("qthr"), fcnt, devs, params["route"], lens, size,
                 params, faults=fdict, faulted=self._meta.get("faulted"))
         self.last_metrics = bundle
-        if want_lat:
-            who, issues, dones = (np.asarray(who), np.asarray(issues),
-                                  np.asarray(dones))
         return who, issues, dones, lens, size, aux, bundle
 
     @staticmethod

@@ -140,18 +140,21 @@ def _body(cfg: MultiCfg, D: int, mspec, want_lat: bool, size: int,
     def step(carry, _):
         slots, now, idx, port_busy, ctr, st, vft, last_arr, aux = carry
         # -- collective 1: winner election (global lowest-(tick, index))
-        cand = jnp.where(idx < lens_l,
-                         jnp.maximum(now, jnp.min(slots, axis=1)), BIG)
-        li0 = jnp.argmin(cand)
-        g = jax.lax.all_gather(
-            jnp.stack([cand[li0], li0.astype(jnp.int64)]), "hosts")
-        w = jnp.argmin(g[:, 0])          # ties -> lowest shard
-        li = g[w, 1]                     # winner's local lane (owner shard)
-        issue = g[w, 0]                  # == max(now, min slot) when valid
-        valid = issue < BIG
-        am = me == w
-        gate = am & valid
-        i_glob = w * Hl + li
+        with jax.named_scope("lfb"):
+            cand = jnp.where(idx < lens_l,
+                             jnp.maximum(now, jnp.min(slots, axis=1)), BIG)
+            li0 = jnp.argmin(cand)
+        with jax.named_scope("collective"):
+            g = jax.lax.all_gather(
+                jnp.stack([cand[li0], li0.astype(jnp.int64)]), "hosts")
+        with jax.named_scope("lfb"):
+            w = jnp.argmin(g[:, 0])        # ties -> lowest shard
+            li = g[w, 1]                   # winner's local lane (owner shard)
+            issue = g[w, 0]                # == max(now, min slot) when valid
+            valid = issue < BIG
+            am = me == w
+            gate = am & valid
+            i_glob = w * Hl + li
         # -- collective 2: the owner's access record, broadcast to all
         ix = jnp.clip(idx[li], 0, L - 1)
         a0 = addrs_l[li, ix]
@@ -171,103 +174,112 @@ def _body(cfg: MultiCfg, D: int, mspec, want_lat: bool, size: int,
             occc_v = occ_v
         rec = jnp.concatenate([jnp.stack([a0, w0]), on_v, pi_v, occ_v,
                                aft_v, occc_v])
-        rec = jax.lax.psum(jnp.where(gate, rec, 0), "hosts")
+        with jax.named_scope("collective"):
+            rec = jax.lax.psum(jnp.where(gate, rec, 0), "hosts")
         a = rec[0]
         wr = rec[1] > 0
         posted = wr if cfg.posted_writes else jnp.zeros((), bool)
         # -- replicated transport walk + QoS mirror (identical on every
         # shard: broadcast inputs, replicated state — byte-for-byte the
         # unsharded loop, reading the record instead of the lookup)
-        t = jnp.where(valid, issue, _i64(0))
-        floor = _i64(0)
-        qacc = aux.get("q")
-        qthr = aux.get("qthr")
-        for h in range(MH):
-            on = rec[2 + h] > 0
-            pi = rec[2 + MH + h]
-            occ_h = rec[2 + 2 * MH + h]
-            aft_h = rec[2 + 3 * MH + h]
-            occ_c = rec[2 + 4 * MH + h]
-            if cfg.qos:
-                qon = on & rep["qos_on"][pi]
-                prev = vft[pi, i_glob]
-                win = occ_c * ACTIVE_WINDOW_OCC
-                w_active = jnp.float64(0.0)
-                for j in cfg.host_order:   # sorted-name order, like dict walk
-                    member = (j == i_glob) | (last_arr[pi, j] + win > t)
-                    w_active = w_active + jnp.where(member,
-                                                    rep["qos_w"][pi, j], 0.0)
-                pace = (occ_c.astype(jnp.float64)
-                        * (w_active / rep["qos_w"][pi, i_glob])
-                        ).astype(jnp.int64)
-                floor = jnp.maximum(
-                    floor, jnp.where(qon & (prev > t), prev + pace, 0))
-                vft = vft.at[pi, i_glob].set(
-                    jnp.where(qon, jnp.maximum(prev, t) + pace, prev))
-                last_arr = last_arr.at[pi, i_glob].set(
-                    jnp.where(qon, t, last_arr[pi, i_glob]))
-                if qthr is not None:
-                    qthr = qthr.at[pi].add(
-                        jnp.where(qon & (prev > t) & valid, 1, 0))
-            start = jnp.maximum(t, port_busy[pi])
-            if qacc is not None:
-                qacc = qacc.at[pi].add(jnp.where(on & valid, start - t, 0))
-            done_h = start + occ_h
-            port_busy = port_busy.at[pi].set(
-                jnp.where(on, done_h, port_busy[pi]))
-            t = jnp.where(on, done_h + aft_h, t)
-        t = t + rep["rt_extra"]
+        with jax.named_scope("transport"):
+            t = jnp.where(valid, issue, _i64(0))
+            floor = _i64(0)
+            qacc = aux.get("q")
+            qthr = aux.get("qthr")
+            for h in range(MH):
+                on = rec[2 + h] > 0
+                pi = rec[2 + MH + h]
+                occ_h = rec[2 + 2 * MH + h]
+                aft_h = rec[2 + 3 * MH + h]
+                occ_c = rec[2 + 4 * MH + h]
+                if cfg.qos:
+                    qon = on & rep["qos_on"][pi]
+                    prev = vft[pi, i_glob]
+                    win = occ_c * ACTIVE_WINDOW_OCC
+                    w_active = jnp.float64(0.0)
+                    # sorted-name order, like the dict walk
+                    for j in cfg.host_order:
+                        member = (j == i_glob) | (last_arr[pi, j] + win > t)
+                        w_active = w_active + jnp.where(
+                            member, rep["qos_w"][pi, j], 0.0)
+                    pace = (occ_c.astype(jnp.float64)
+                            * (w_active / rep["qos_w"][pi, i_glob])
+                            ).astype(jnp.int64)
+                    floor = jnp.maximum(
+                        floor, jnp.where(qon & (prev > t), prev + pace, 0))
+                    vft = vft.at[pi, i_glob].set(
+                        jnp.where(qon, jnp.maximum(prev, t) + pace, prev))
+                    last_arr = last_arr.at[pi, i_glob].set(
+                        jnp.where(qon, t, last_arr[pi, i_glob]))
+                    if qthr is not None:
+                        qthr = qthr.at[pi].add(
+                            jnp.where(qon & (prev > t) & valid, 1, 0))
+                start = jnp.maximum(t, port_busy[pi])
+                if qacc is not None:
+                    qacc = qacc.at[pi].add(
+                        jnp.where(on & valid, start - t, 0))
+                done_h = start + occ_h
+                port_busy = port_busy.at[pi].set(
+                    jnp.where(on, done_h, port_busy[pi]))
+                t = jnp.where(on, done_h + aft_h, t)
+            t = t + rep["rt_extra"]
         # -- SPMD media step: every shard runs it on lane `li` of its own
         # local state, only the owner commits (en gate); non-owner outputs
         # are garbage and every use below is owner-gated
-        if cfg.stack.kind == DRAM:
-            p_med = {"occ": rep["dev_occ"][i_glob],
-                     "load": rep["dev_load"][i_glob],
-                     "pack": rep["dev_pack"][i_glob]}
-        else:
-            p_med = rep
+        with jax.named_scope("media"):
+            if cfg.stack.kind == DRAM:
+                p_med = {"occ": rep["dev_occ"][i_glob],
+                         "load": rep["dev_load"][i_glob],
+                         "pack": rep["dev_pack"][i_glob]}
+            else:
+                p_med = rep
         st, out = stack.step(cfg.stack, p_med, st, dict(
             lane=li, flash_lane=li, t=t, addr=a, write=wr, posted=posted,
             ctr=ctr, en=gate))
         done = out["done"]
         if cfg.qos:
-            done = jnp.maximum(done, floor)
-        bad_l, gcs_l = stack.flash_health(st)
-        if mspec is not None:
-            aux = {**aux,
-                   "acc": _metrics.acc_update(
-                       mspec, aux["acc"], host=i_glob, dev=i_glob, n_hosts=H,
-                       n_devs=cfg.num_devs, issue=issue, done=done,
-                       size=size, hit=out["hit"], valid=gate),
-                   "med": aux["med"].at[i_glob].add(
-                       _metrics.media_increments(cfg.stack.kind, wr, out)
-                       * jnp.where(gate, 1, 0)),
-                   "q": qacc}
-            if qthr is not None:
-                aux = {**aux, "qthr": qthr}
-            if "flash" in aux:
-                aux = {**aux, "flash": jnp.where(
-                    valid, stack.flash_counters(st), aux["flash"])}
-            if "faults" in aux:
-                aux = {**aux, "faults": jnp.where(
-                    valid, jnp.stack(stack.fault_counters(st)),
-                    aux["faults"])}
-        if not want_lat:
-            aux = {**aux,
-                   "first": aux["first"].at[li].min(
-                       jnp.where(gate, issue, BIG)),
-                   "last": aux["last"].at[li].max(
-                       jnp.where(gate, done, _i64(-BIG))),
-                   "sum": aux["sum"].at[li].add(
-                       jnp.where(gate, done - issue, 0)),
-                   "cnt": aux["cnt"].at[li].add(jnp.where(gate, 1, 0)),
-                   "bad": aux["bad"] | (bad_l & valid),
-                   "gcs": jnp.where(valid, gcs_l, aux["gcs"])}
-        k = jnp.argmin(slots[li])
-        slots = slots.at[li, k].set(jnp.where(gate, done, slots[li, k]))
-        now = now.at[li].set(
-            jnp.where(gate, issue + rep["issue_ov"], now[li]))
-        idx = idx.at[li].set(jnp.where(gate, idx[li] + 1, idx[li]))
+            with jax.named_scope("transport"):
+                done = jnp.maximum(done, floor)
+        with jax.named_scope("telemetry"):
+            bad_l, gcs_l = stack.flash_health(st)
+            if mspec is not None:
+                aux = {**aux,
+                       "acc": _metrics.acc_update(
+                           mspec, aux["acc"], host=i_glob, dev=i_glob,
+                           n_hosts=H, n_devs=cfg.num_devs, issue=issue,
+                           done=done,
+                           size=size, hit=out["hit"], valid=gate),
+                       "med": aux["med"].at[i_glob].add(
+                           _metrics.media_increments(cfg.stack.kind, wr, out)
+                           * jnp.where(gate, 1, 0)),
+                       "q": qacc}
+                if qthr is not None:
+                    aux = {**aux, "qthr": qthr}
+                if "flash" in aux:
+                    aux = {**aux, "flash": jnp.where(
+                        valid, stack.flash_counters(st), aux["flash"])}
+                if "faults" in aux:
+                    aux = {**aux, "faults": jnp.where(
+                        valid, jnp.stack(stack.fault_counters(st)),
+                        aux["faults"])}
+            if not want_lat:
+                aux = {**aux,
+                       "first": aux["first"].at[li].min(
+                           jnp.where(gate, issue, BIG)),
+                       "last": aux["last"].at[li].max(
+                           jnp.where(gate, done, _i64(-BIG))),
+                       "sum": aux["sum"].at[li].add(
+                           jnp.where(gate, done - issue, 0)),
+                       "cnt": aux["cnt"].at[li].add(jnp.where(gate, 1, 0)),
+                       "bad": aux["bad"] | (bad_l & valid),
+                       "gcs": jnp.where(valid, gcs_l, aux["gcs"])}
+        with jax.named_scope("lfb"):
+            k = jnp.argmin(slots[li])
+            slots = slots.at[li, k].set(jnp.where(gate, done, slots[li, k]))
+            now = now.at[li].set(
+                jnp.where(gate, issue + rep["issue_ov"], now[li]))
+            idx = idx.at[li].set(jnp.where(gate, idx[li] + 1, idx[li]))
         ys = ((i_glob, issue, jnp.where(gate, done, 0),
                jnp.where(bad_l, 1, 0), gcs_l) if want_lat else None)
         return ((slots, now, idx, port_busy, ctr + 1, st, vft, last_arr,
@@ -276,29 +288,30 @@ def _body(cfg: MultiCfg, D: int, mspec, want_lat: bool, size: int,
     carry, ys = jax.lax.scan(step, init, None, length=H * L, unroll=block)
     aux = carry[8]
     # -- post-scan reductions: every returned leaf becomes replicated
-    if want_lat:
-        who, issues, d_gated, bad_i, gcs_loc = ys
-        dones = jax.lax.psum(d_gated, "hosts")
-        bad = jax.lax.psum(bad_i, "hosts") > 0
-        gcs = jax.lax.psum(gcs_loc, "hosts")
-    else:
-        who = issues = dones = bad = gcs = None
-    if mspec is not None:
-        aux = {**aux,
-               "acc": jax.lax.psum(aux["acc"], "hosts"),
-               "med": jax.lax.psum(aux["med"], "hosts")}
-        if "flash" in aux:
-            aux = {**aux, "flash": jax.lax.all_gather(
-                aux["flash"], "hosts").reshape(H, -1)}
-        if "faults" in aux:
-            aux = {**aux, "faults": jax.lax.psum(aux["faults"], "hosts")}
-    if not want_lat:
-        gathered = {k: jax.lax.all_gather(aux[k], "hosts").reshape(H)
-                    for k in ("first", "last", "sum", "cnt")}
-        aux = {**aux, **gathered,
-               "bad": jax.lax.psum(
-                   jnp.where(aux["bad"], 1, 0), "hosts") > 0,
-               "gcs": jax.lax.psum(aux["gcs"], "hosts")}
+    with jax.named_scope("collective"):
+        if want_lat:
+            who, issues, d_gated, bad_i, gcs_loc = ys
+            dones = jax.lax.psum(d_gated, "hosts")
+            bad = jax.lax.psum(bad_i, "hosts") > 0
+            gcs = jax.lax.psum(gcs_loc, "hosts")
+        else:
+            who = issues = dones = bad = gcs = None
+        if mspec is not None:
+            aux = {**aux,
+                   "acc": jax.lax.psum(aux["acc"], "hosts"),
+                   "med": jax.lax.psum(aux["med"], "hosts")}
+            if "flash" in aux:
+                aux = {**aux, "flash": jax.lax.all_gather(
+                    aux["flash"], "hosts").reshape(H, -1)}
+            if "faults" in aux:
+                aux = {**aux, "faults": jax.lax.psum(aux["faults"], "hosts")}
+        if not want_lat:
+            gathered = {k: jax.lax.all_gather(aux[k], "hosts").reshape(H)
+                        for k in ("first", "last", "sum", "cnt")}
+            aux = {**aux, **gathered,
+                   "bad": jax.lax.psum(
+                       jnp.where(aux["bad"], 1, 0), "hosts") > 0,
+                   "gcs": jax.lax.psum(aux["gcs"], "hosts")}
     return who, issues, dones, bad, gcs, aux
 
 

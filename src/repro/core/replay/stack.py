@@ -58,6 +58,7 @@ from repro.core.replay.spec import (
     SSD_CACHE,
     StackConfig,
 )
+from repro.obs import scopes
 
 # Plain ints: they stay weakly typed so they promote to int64 inside the
 # enable_x64 scope (a jnp.int64 built at import time would truncate to int32).
@@ -78,6 +79,7 @@ def _i64(x):
 
 
 # -------------------------------------------------------------- flash (PAL)
+@scopes.scoped("flash")
 def _pal_read(cfg: StackConfig, p: Dict, f: Dict, t, ppn, en):
     """Mirror of :meth:`PAL._schedule` (read path, program-suspend rule).
 
@@ -114,6 +116,7 @@ def _pal_read(cfg: StackConfig, p: Dict, f: Dict, t, ppn, en):
     return f, done
 
 
+@scopes.scoped("flash")
 def _pal_prog(cfg: StackConfig, p: Dict, f: Dict, t, ppn, en):
     """Mirror of :meth:`PAL._schedule` (program path: bus in, then array)."""
     C, D = cfg.channels, cfg.dies_per_channel
@@ -132,6 +135,7 @@ def _pal_prog(cfg: StackConfig, p: Dict, f: Dict, t, ppn, en):
     return f, done
 
 
+@scopes.scoped("flash")
 def _pal_erase(cfg: StackConfig, p: Dict, f: Dict, t, ppn, en):
     """Mirror of :meth:`PAL.erase_block` (array-only, program waits out)."""
     C, D = cfg.channels, cfg.dies_per_channel
@@ -173,6 +177,7 @@ def _free_append(cfg: StackConfig, f: Dict, v, en):
 
 
 # -------------------------------------------------------------- FTL + GC
+@scopes.scoped("flash")
 def _collect(cfg: StackConfig, p: Dict, f: Dict, now):
     """Mirror of :meth:`FTL._collect`: greedy victim (fewest valid pages,
     excluding the write block and free blocks, ties to the lowest block id),
@@ -256,6 +261,7 @@ def _ftl_invalidate(cfg: StackConfig, f: Dict, lpn, en):
                 jnp.where(has, FREE, f["p2l"][osafe]))}
 
 
+@scopes.scoped("flash")
 def _alloc_ppn(cfg: StackConfig, p: Dict, f: Dict, t, en):
     """Mirror of :meth:`FTL._next_ppn`: returns ``(f, ppn, gc_done)``."""
     need = f["wpp"] >= cfg.pages_per_block
@@ -288,6 +294,7 @@ def _alloc_ppn(cfg: StackConfig, p: Dict, f: Dict, t, en):
     return f, ppn, jnp.where(en, gc_done, t)
 
 
+@scopes.scoped("flash")
 def _hil_write(cfg: StackConfig, p: Dict, f: Dict, t, lpn, en):
     """HIL overhead + FTL write: invalidate (GC stacks), allocate — running
     greedy GC when the free pool is at the watermark — then program."""
@@ -309,6 +316,7 @@ def _hil_write(cfg: StackConfig, p: Dict, f: Dict, t, lpn, en):
     return _pal_prog(cfg, p, f, t1, ppn, en)
 
 
+@scopes.scoped("flash")
 def _hil_read(cfg: StackConfig, p: Dict, f: Dict, t, ppn, en):
     """HIL overhead + FTL read of a programmed page (callers check the
     mapping table first, exactly like the cache's ``is_written`` gate)."""
@@ -608,6 +616,7 @@ def _n_lanes(tree) -> int:
     return jax.tree.leaves(tree)[0].shape[0]
 
 
+@scopes.scoped("media")
 def step(cfg: StackConfig, p: Dict, state: Dict, access: Dict
          ) -> Tuple[Dict, Dict]:
     """One access against the stacked state.

@@ -126,6 +126,20 @@ def _decode_snapshot(flat: Dict, extra: Dict, *, n: int,
     }
 
 
+def _feed_spans(chunks):
+    """``chunks``, each one read inside a ``replay.feed`` profiler span
+    (on the thread that iterates, the prefetcher's feed thread)."""
+    from jax.profiler import TraceAnnotation
+
+    it = iter(chunks)
+    while True:
+        with TraceAnnotation("replay.feed"):
+            item = next(it, None)
+        if item is None:
+            return
+        yield item
+
+
 def replay_stream(store, device, *, chunk_size: int,
                   prefetch_depth: int = 2, outstanding: int = 32,
                   issue_overhead_ns: float = 0.5,
@@ -198,8 +212,9 @@ def replay_stream(store, device, *, chunk_size: int,
                 mgr.save(int(seen), flat, extra=extra)
                 written += 1
 
-    pf = Prefetcher(store.chunks(chunk, start=seen0) if seen0
-                    else store.chunks(chunk), depth=prefetch_depth)
+    pf = Prefetcher(_feed_spans(store.chunks(chunk, start=seen0) if seen0
+                                else store.chunks(chunk)),
+                    depth=prefetch_depth)
     try:
         res = engine.run_store(store, chunk_size=chunk,
                                start_tick=start_tick,

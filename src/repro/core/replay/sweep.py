@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import enable_x64
+from jax.profiler import TraceAnnotation
 
 from repro.core.replay import stack
 from repro.core.replay.engine import _scan_stack
@@ -45,6 +46,7 @@ from repro.core.replay.multihost import MultiHostReplay, _run_multi
 from repro.core.replay.spec import SSD_CACHE, ReplayUnsupported, build_stack
 from repro.core.replay.stack import MAX_ACCESSES, PAGE_FIELD, _i64
 from repro.core.workloads.driver import MultiHostResult
+from repro.obs import scopes
 
 # A disabled frame: never matches (page field all-ones is reserved) and is
 # never chosen as victim (above every valid packed value and every -1).
@@ -120,10 +122,11 @@ def cache_design_sweep(device, addrs, writes, *,
         raise ReplayUnsupported(
             f"trace longer than {MAX_ACCESSES} accesses (packed-stamp "
             "budget); split the trace")
-    cfg, params = build_stack(
-        device, size=64, outstanding=outstanding,
-        issue_overhead_ns=issue_overhead_ns, posted_writes=posted_writes,
-        n_accesses=addrs.shape[-1], max_addr=int(addrs.max(initial=0)))
+    with TraceAnnotation("replay.build"):
+        cfg, params = build_stack(
+            device, size=64, outstanding=outstanding,
+            issue_overhead_ns=issue_overhead_ns, posted_writes=posted_writes,
+            n_accesses=addrs.shape[-1], max_addr=int(addrs.max(initial=0)))
     if cfg.kind != SSD_CACHE:
         raise ReplayUnsupported("cache_design_sweep needs a cached CXL-SSD")
     if not cfg.cache_assoc:
@@ -145,13 +148,16 @@ def cache_design_sweep(device, addrs, writes, *,
 
     trace_ax = 0 if addrs.ndim == 2 else None
     with enable_x64(True):
-        pj = {k: jnp.asarray(v) for k, v in params.items()}
-        issues, dones, flags, final, _ = _run_cache_lanes(
-            cfg, pj, (jnp.asarray(addrs), jnp.asarray(writes)),
-            frozenset(batched), trace_ax)
-        issues = np.asarray(issues)
-        dones = np.asarray(dones)
-        flags = np.asarray(flags)
+        with TraceAnnotation("replay.put"):
+            pj = {k: jnp.asarray(v) for k, v in params.items()}
+            xs = (jnp.asarray(addrs), jnp.asarray(writes))
+        with TraceAnnotation("replay.run"):
+            issues, dones, flags, final, _ = scopes.run(
+                _run_cache_lanes, cfg, pj, xs, frozenset(batched), trace_ax)
+        with TraceAnnotation("replay.fetch"):
+            issues = np.asarray(issues)
+            dones = np.asarray(dones)
+            flags = np.asarray(flags)
         flash = final["flash"]
         if flash is not None and "bad" in flash:
             # certify-or-refuse, per lane: a lane whose FTL ran out of free
@@ -164,15 +170,16 @@ def cache_design_sweep(device, addrs, writes, *,
                 raise ReplayUnsupported(
                     f"sweep lane(s) {bad_lanes}: FTL ran out of free blocks "
                     "during GC (device overfilled); use engine='python'")
-    lat = dones - issues
-    return {
-        "latency_ticks": lat,
-        "hit_flags": (flags & 1).astype(bool),
-        "evict_flags": (flags & 2).astype(bool),
-        "sum_latency_ticks": lat.sum(axis=1),
-        "hit_rate": (flags & 1).mean(axis=1),
-        "elapsed_ticks": dones.max(axis=1) - issues[:, 0],
-    }
+    with TraceAnnotation("replay.finish"):
+        lat = dones - issues
+        return {
+            "latency_ticks": lat,
+            "hit_flags": (flags & 1).astype(bool),
+            "evict_flags": (flags & 2).astype(bool),
+            "sum_latency_ticks": lat.sum(axis=1),
+            "hit_rate": (flags & 1).mean(axis=1),
+            "elapsed_ticks": dones.max(axis=1) - issues[:, 0],
+        }
 
 
 def host_count_sweep(targets: Sequence, traces: Sequence,
@@ -242,8 +249,8 @@ def host_count_sweep(targets: Sequence, traces: Sequence,
         np.where(np.arange(lens.size) < h, lens, 0) for h in host_counts])
     with enable_x64(True):
         pj = jax.tree.map(jnp.asarray, params)
-        who, issues, dones, bad, _, _ = _run_multi_lanes(
-            cfg, pj, jnp.asarray(devs), jnp.asarray(addrs),
+        who, issues, dones, bad, _, _ = scopes.run(
+            _run_multi_lanes, cfg, pj, jnp.asarray(devs), jnp.asarray(addrs),
             jnp.asarray(writes), jnp.asarray(lane_lens))
         who = np.asarray(who)
         issues = np.asarray(issues)
