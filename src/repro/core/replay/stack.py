@@ -78,6 +78,25 @@ def _i64(x):
     return jnp.asarray(x, jnp.int64)
 
 
+# A TPU has no 64-bit divide: XLA expands each int64 `//` or `%` into some
+# 1,700 serial scalar instructions.  The step's static divisors (page size,
+# channels, dies, pages per block) are powers of two in the usual flash
+# geometries, and for those a floor division is an arithmetic shift and a
+# floor modulo a mask, negative dividends included.
+def _floordiv(x, d: int):
+    """``x // d`` for a static positive ``d``."""
+    if d & (d - 1):
+        return x // d
+    return x >> (d.bit_length() - 1)
+
+
+def _mod(x, d: int):
+    """``x % d`` for a static positive ``d``."""
+    if d & (d - 1):
+        return x % d
+    return x & (d - 1)
+
+
 # -------------------------------------------------------------- flash (PAL)
 @scopes.scoped("flash")
 def _pal_read(cfg: StackConfig, p: Dict, f: Dict, t, ppn, en):
@@ -89,8 +108,8 @@ def _pal_read(cfg: StackConfig, p: Dict, f: Dict, t, ppn, en):
     the plan on its ``_rd_seq`` (the sequence only advances on enabled
     reads, like the python path only calls the PAL for real reads)."""
     C, D = cfg.channels, cfg.dies_per_channel
-    ch = ppn % C
-    i = ch * D + (ppn // C) % D
+    ch = _mod(ppn, C)
+    i = ch * D + _mod(_floordiv(ppn, C), D)
     db, dp, cb = f["die_busy"], f["die_prog"], f["chan_busy"]
     dbi, dpi, cbi = db[i], dp[i], cb[ch]
     read_t, xfer = p["read_t"], p["xfer_page"]
@@ -120,8 +139,8 @@ def _pal_read(cfg: StackConfig, p: Dict, f: Dict, t, ppn, en):
 def _pal_prog(cfg: StackConfig, p: Dict, f: Dict, t, ppn, en):
     """Mirror of :meth:`PAL._schedule` (program path: bus in, then array)."""
     C, D = cfg.channels, cfg.dies_per_channel
-    ch = ppn % C
-    i = ch * D + (ppn // C) % D
+    ch = _mod(ppn, C)
+    i = ch * D + _mod(_floordiv(ppn, C), D)
     db, dp, cb = f["die_busy"], f["die_prog"], f["chan_busy"]
     dbi, dpi, cbi = db[i], dp[i], cb[ch]
     ds = jnp.maximum(jnp.maximum(t, dbi), dpi)
@@ -139,8 +158,8 @@ def _pal_prog(cfg: StackConfig, p: Dict, f: Dict, t, ppn, en):
 def _pal_erase(cfg: StackConfig, p: Dict, f: Dict, t, ppn, en):
     """Mirror of :meth:`PAL.erase_block` (array-only, program waits out)."""
     C, D = cfg.channels, cfg.dies_per_channel
-    ch = ppn % C
-    i = ch * D + (ppn // C) % D
+    ch = _mod(ppn, C)
+    i = ch * D + _mod(_floordiv(ppn, C), D)
     dbi = f["die_busy"][i]
     start = jnp.maximum(jnp.maximum(t, dbi), f["die_prog"][i])
     done = start + p["erase_t"]
@@ -219,7 +238,8 @@ def _collect(cfg: StackConfig, p: Dict, f: Dict, now):
         p2l = p2l.at[new_ppn].set(jnp.where(live, lpn, p2l[new_ppn]))
         l2p = f["l2p"].at[lsafe].set(
             jnp.where(live, new_ppn.astype(jnp.int32), f["l2p"][lsafe]))
-        valid = f["valid"].at[new_ppn // ppb].add(jnp.where(live, 1, 0))
+        valid = f["valid"].at[_floordiv(new_ppn, ppb)].add(
+            jnp.where(live, 1, 0))
         valid = valid.at[victim].add(jnp.where(live, -1, 0))
         f = {**f, "p2l": p2l, "l2p": l2p, "valid": valid}
         if "c_gw" in f:
@@ -255,8 +275,9 @@ def _ftl_invalidate(cfg: StackConfig, f: Dict, lpn, en):
     has = en & (old >= 0)
     osafe = jnp.maximum(old, 0)
     return {**f,
-            "valid": f["valid"].at[old // cfg.pages_per_block].add(
-                jnp.where(has, -1, 0)),
+            "valid": f["valid"].at[
+                _floordiv(old, cfg.pages_per_block)].add(
+                    jnp.where(has, -1, 0)),
             "p2l": f["p2l"].at[osafe].set(
                 jnp.where(has, FREE, f["p2l"][osafe]))}
 
@@ -311,8 +332,9 @@ def _hil_write(cfg: StackConfig, p: Dict, f: Dict, t, lpn, en):
         f = {**f,
              "p2l": f["p2l"].at[ppn].set(
                  jnp.where(en, lpn.astype(jnp.int32), f["p2l"][ppn])),
-             "valid": f["valid"].at[ppn // cfg.pages_per_block].add(
-                 jnp.where(en, 1, 0))}
+             "valid": f["valid"].at[
+                 _floordiv(ppn, cfg.pages_per_block)].add(
+                     jnp.where(en, 1, 0))}
     return _pal_prog(cfg, p, f, t1, ppn, en)
 
 
@@ -351,7 +373,7 @@ def _buf_step(cfg: StackConfig, p: Dict, md: Dict, f: Dict, t, addr, wr,
               posted, ctr):
     """CXL-SSD page-register buffer: LRU over a handful of open pages;
     misses amplify to 4 KB flash ops (read-modify-write for writes)."""
-    page = addr // cfg.page_bytes
+    page = _floordiv(addr, cfg.page_bytes)
     frames = md["frames"]
     pfield = page << 1
     match = (frames & PAGE_FIELD) == pfield
@@ -399,7 +421,7 @@ def _cache_step(cfg: StackConfig, p: Dict, md: Dict, f: Dict, t, addr, wr,
     """The paper's DRAM cache layer, one access: MSHR coalesce -> resident
     hit -> miss (MSHR stall, evict + writeback queue, flash fill).  Mirrors
     :meth:`repro.core.cache.dram_cache.DRAMCache.access` branch for branch."""
-    page = addr // cfg.page_bytes
+    page = _floordiv(addr, cfg.page_bytes)
     frames = md["frames"]
     pfield = page << 1
 

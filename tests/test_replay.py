@@ -659,6 +659,149 @@ def test_multihost_cached_block_size_invariance():
         _assert_multi_equal(py, rp)
 
 
+# ------------------------------------ miss-path gates, access by access
+# A 4-frame cache with 2 MSHRs and 1 writeback slot on a hot/cold mix:
+# every gate of the miss path fires (MSHR stall and kill, clean and dirty
+# evictions, a full writeback queue, coalesced loads and stores).
+GATE_KW = dict(capacity_bytes=4 * 4096, mshr_entries=2, writeback_buffer=1)
+# per-access events, in the order of the scan's flag bits 0..5
+GATE_EVENTS = ("hits", "writebacks", "misses", "mshr_coalesced",
+               "mshr_stalls", "evictions")
+GATE_CASES = [(policy, wf, lane)
+              for policy in ("lru", "fifo", "direct")
+              for wf in (0.0, 0.5, 1.0)
+              for lane in ("scan", "sweep", "multihost")
+              # the sweep's policy axis is lru/fifo
+              if not (lane == "sweep" and policy == "direct")]
+
+
+class _EventLog:
+    """An interpreted target that logs, per access, the latency and the
+    cache counters the access moved."""
+
+    def __init__(self, target, dev):
+        self.target, self.dev, self.rows = target, dev, []
+
+    def service(self, now, addr, size, write, posted=False):
+        from repro.core.replay.metrics import media_counters_of
+
+        before = media_counters_of(self.dev)
+        done = self.target.service(now, addr, size, write, posted)
+        after = media_counters_of(self.dev)
+        self.rows.append([done - now]
+                         + [after[k] - before[k] for k in GATE_EVENTS])
+        return done
+
+
+def _gate_dev(policy):
+    return make_device("cxl-ssd-cache", cache_cfg=DRAMCacheConfig(
+        policy=policy, **GATE_KW))
+
+
+def _gate_trace(seed, write_frac, n=384, pages=24, hot=3):
+    """Half the accesses reuse 3 hot pages back to back (coalesced loads
+    and stores), half spread over 24 pages (misses, evictions)."""
+    rng = np.random.default_rng(seed)
+    page = np.where(rng.random(n) < 0.5, rng.integers(0, hot, n),
+                    rng.integers(0, pages, n))
+    addrs = (page * 4096 + rng.integers(0, 64, n) * 64).astype(np.int64)
+    return addrs, rng.random(n) < write_frac
+
+
+def _gate_mounts(policy, nh=2):
+    fab = Fabric.build("two_level", num_hosts=nh, num_devices=nh,
+                       num_leaves=2)
+    return [fab.mount(f"h{i}", f"d{i}", _gate_dev(policy))
+            for i in range(nh)]
+
+
+def _gate_rows(targets, devs, rows):
+    logs = [_EventLog(t, d) for t, d in zip(targets, devs)]
+    MultiHostDriver(logs, outstanding=8).run(rows)
+    return [np.asarray(log.rows, np.int64) for log in logs]
+
+
+@pytest.mark.parametrize("policy,write_frac,lane", GATE_CASES)
+def test_miss_path_gates_exact_per_access(policy, write_frac, lane):
+    """Every lane that steps a cached CXL-SSD matches the interpreted
+    device access for access: latency, hit, writeback and (where the lane
+    reports them) miss, coalesce, MSHR stall and eviction."""
+    import jax
+    import jax.numpy as jnp
+    from jax import enable_x64
+
+    from repro.core.replay import MetricsSpec
+    from repro.core.replay.engine import _run_stack
+    from repro.core.replay.metrics import media_counters_of
+    from repro.core.replay.spec import build_stack
+    from repro.core.replay.sweep import cache_design_sweep
+
+    hosts = 2 if lane == "multihost" else 1
+    traces = [_gate_trace(170 + h, write_frac) for h in range(hosts)]
+    tuples = [[(int(a), 64, bool(w)) for a, w in zip(*tr)] for tr in traces]
+    if lane == "multihost":
+        targets = _gate_mounts(policy)
+        devs = [t.inner for t in targets]
+    else:
+        targets = devs = [_gate_dev(policy)]
+    rows = _gate_rows(targets, devs, tuples)
+    events = dict(zip(GATE_EVENTS, sum(r[:, 1:].sum(0) for r in rows)))
+    assert events["mshr_stalls"] and events["mshr_coalesced"]
+    assert events["evictions"] > events["writebacks"] or write_frac == 1.0
+    assert (events["writebacks"] > 0) == (write_frac > 0)
+
+    addrs, writes = traces[0]
+    if lane == "scan":
+        with enable_x64(True):
+            cfg, params = build_stack(
+                _gate_dev(policy), size=64, outstanding=8,
+                issue_overhead_ns=0.5, posted_writes=True,
+                n_accesses=addrs.size,
+                max_addr=int(addrs.max()), counters=True)
+            issues, dones, flags, _, _ = _run_stack(
+                cfg, jax.tree.map(jnp.asarray, params), jnp.asarray(addrs),
+                jnp.asarray(writes), jnp.asarray(0, jnp.int64), 1,
+                MetricsSpec(), True, 64)
+        got = np.column_stack(
+            [np.asarray(dones) - np.asarray(issues)]
+            + [(np.asarray(flags) >> b) & 1 for b in range(6)])
+        np.testing.assert_array_equal(got, rows[0])
+    elif lane == "sweep":
+        out = cache_design_sweep(_gate_dev(policy), addrs, writes,
+                                 capacity_frames=[4],
+                                 is_lru=[policy == "lru"], outstanding=8)
+        got = np.column_stack([out["latency_ticks"][0],
+                               out["hit_flags"][0], out["evict_flags"][0]])
+        np.testing.assert_array_equal(got, rows[0][:, :3])
+    else:
+        res, lat = MultiHostReplay(_gate_mounts(policy), outstanding=8,
+                                   metrics=MetricsSpec()).run_recorded(tuples)
+        for h in range(hosts):
+            np.testing.assert_array_equal(lat[h], rows[h][:, 0])
+        media = res.metrics.to_jsonable()["media"]
+        for m, d in zip(media, devs):
+            want = media_counters_of(d)
+            assert {k: m[k] for k in want} == want
+
+
+@pytest.mark.parametrize("d", [1, 4, 8, 256, 4096, 6])
+def test_static_divisor_matches_floor_division(d):
+    """The step's shift/mask division equals int64 floor division and
+    modulo, negative dividends and non-powers of two included."""
+    import jax.numpy as jnp
+    from jax import enable_x64
+
+    from repro.core.replay.stack import _floordiv, _mod
+
+    x = np.array([-(1 << 62), -4097, -4096, -1, 0, 1, 7, 4095, 4096,
+                  (1 << 62) - 1], np.int64)
+    with enable_x64(True):
+        q = np.asarray(_floordiv(jnp.asarray(x), d))
+        r = np.asarray(_mod(jnp.asarray(x), d))
+    np.testing.assert_array_equal(q, x // d)
+    np.testing.assert_array_equal(r, x % d)
+
+
 def test_multihost_pmem_pool_exact():
     """PMEM pools ride the same stacked-state path (open-row state is a
     per-device lane)."""

@@ -8,6 +8,8 @@ or speed.  The topology is described inside a fixture, never at import:
 only the worker that runs this file may load the TPU library.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -81,7 +83,9 @@ def test_cache_sim_fused_compiles_at_paper_geometry(one_chip, num_sets,
     assert _device_bytes(compiled) < HBM_BYTES
 
 
-def test_single_host_scan_compiles_for_table1_cached_ssd(one_chip):
+@pytest.fixture(scope="module")
+def table1_scan(one_chip):
+    """The single-host scan of the Table I cached CXL-SSD, compiled."""
     from repro.core.replay.engine import _run_stack
 
     n = 1 << 16
@@ -97,7 +101,22 @@ def test_single_host_scan_compiles_for_table1_cached_ssd(one_chip):
             jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=one_chip),
             jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip),
             1, MetricsSpec(), True, 64).compile()
+    return cfg, compiled
+
+
+def test_single_host_scan_compiles_for_table1_cached_ssd(table1_scan):
+    _, compiled = table1_scan
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_table1_scan_has_no_int64_division(table1_scan):
+    """The step divides only by static powers of two, as shifts and masks:
+    a TPU has no 64-bit divide, and XLA expands each int64 ``//`` or ``%``
+    into some 1,700 serial scalar instructions on every step."""
+    _, compiled = table1_scan
+    divisions = re.findall(r'op_name="[^"]*jit\((?:floor_divide|remainder)\)',
+                           compiled.as_text())
+    assert not divisions, f"{len(divisions)} instructions expand a division"
 
 
 def test_sharded_fleet_runner_compiles_on_four_chip_mesh(topo):
