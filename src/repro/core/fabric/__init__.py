@@ -11,7 +11,8 @@ The vectorized congestion estimator lives in
 """
 
 from repro.core.fabric.fabric import Fabric, FabricAttachedDevice
-from repro.core.fabric.pool import HostPortView, MemoryPool, PoolAddressMapper
+from repro.core.fabric.pool import (HostPortView, LogicalDeviceRangeError,
+                                   MemoryPool, PoolAddressMapper)
 from repro.core.fabric.routing import RoutingTable, flow_choices, flow_hash
 from repro.core.fabric.switch import SwitchPort
 from repro.core.fabric.topology import (
@@ -28,6 +29,7 @@ from repro.core.fabric.topology import (
 __all__ = [
     "Fabric", "FabricAttachedDevice",
     "MemoryPool", "HostPortView", "PoolAddressMapper",
+    "LogicalDeviceRangeError",
     "RoutingTable", "SwitchPort", "flow_hash", "flow_choices",
     "Topology", "build_topology", "TOPOLOGY_BUILDERS",
     "direct", "single_switch", "two_level", "spine_leaf", "mesh",

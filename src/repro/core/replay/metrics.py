@@ -56,7 +56,7 @@ import numpy as np
 
 from repro.core.engine import to_ns
 from repro.core.fabric.fabric import LINE_BYTES, FabricAttachedDevice
-from repro.core.fabric.pool import HostPortView
+from repro.core.fabric.pool import HostPortView, ld_table
 from repro.core.replay.spec import DRAM, PMEM, SSD_BUF, SSD_CACHE
 
 # Counter schema per media kind — names match the python stats dicts they
@@ -336,7 +336,8 @@ class MetricsBundle:
                  ports: Optional[Dict[str, Dict]] = None,
                  ecmp: Optional[Dict[str, List[int]]] = None,
                  deferred: Optional[Callable] = None,
-                 faults: Optional[Dict[str, int]] = None) -> None:
+                 faults: Optional[Dict[str, int]] = None,
+                 lds: Optional[List[Dict[str, int]]] = None) -> None:
         if deferred is None and (hist is None or dev_hist is None
                                  or windows is None or media is None):
             raise ValueError(
@@ -351,6 +352,10 @@ class MetricsBundle:
         # FAULT_COUNTERS dict when a fault plan was active; None otherwise
         # (kept out of to_jsonable when None — schema stability)
         self.faults = faults
+        # per host, its logical device's {"ld", "base", "bytes"} in an LD
+        # pool, so port bytes by host read per LD; None otherwise (kept
+        # out of to_jsonable when None, like faults)
+        self.lds = lds
         self._hist = hist
         self._dev_hist = dev_hist
         self._windows = windows
@@ -474,6 +479,8 @@ class MetricsBundle:
         }
         if self.faults is not None:
             out["faults"] = {k: int(self.faults[k]) for k in FAULT_COUNTERS}
+        if self.lds is not None:
+            out["lds"] = [dict(d) for d in self.lds]
         return out
 
 
@@ -590,11 +597,11 @@ def _target_layout(targets: Sequence):
         devices = list(pool.devices)
         mapper = pool.mapper
 
-        def dev_of(_i):
-            return lambda addr: mapper.map(addr)[0]
+        def dev_of(view):
+            return lambda addr: mapper.map(view.pool_address(addr))[0]
 
         return (hosts, labels, devices, pool.fabric,
-                [dev_of(i) for i in range(len(targets))])
+                [dev_of(t) for t in targets])
     if isinstance(first, FabricAttachedDevice):
         hosts = [t.host for t in targets]
         labels = [t.device_node for t in targets]
@@ -673,6 +680,7 @@ def collect_python(spec: MetricsSpec, targets: Sequence,
               sorted(getattr(fabric, "ecmp_counts", {}).items())}
         if fabric is not None else {},
         faults=fault_counters_of(targets, poisoned),
+        lds=ld_table(targets),
     )
     return bundle
 
@@ -928,7 +936,8 @@ def bundle_multi_fused(spec: MetricsSpec, meta: Dict, mcfg, acc, med,
         spec=spec, hosts=list(hosts), devices=list(nodes), hist=hist,
         dev_hist=dev_hist, windows=windows, media=media,
         flash=_flash_dicts(flash_cnt), ports=ports, ecmp=ecmp,
-        faults=faults)
+        faults=faults,
+        lds=meta.get("lds"))
 
 
 # -------------------------------------------------- availability (faults)

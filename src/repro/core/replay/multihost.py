@@ -34,9 +34,12 @@ Supported targets (homogeneous): :class:`FabricAttachedDevice` mounts and
 DRAM-class (heterogeneous timing allowed), PMEM, CXL-SSD, cached CXL-SSD
 (lru/fifo/direct, identical configuration across targets).  The pool's
 address mapper is applied host-side (it is a pure function of the address),
-so interleave and segment modes cost nothing in the scan.  Anything else
-raises :class:`ReplayUnsupported` naming the widest lane that still covers
-the shape (the ``engine='python'`` fallback) — lanes refuse, they never
+so interleave and segment modes cost nothing in the scan.  A pool's
+logical-device partitions are applied there too: each view's LD base is
+added, and an access outside its LD is refused with the interpreted view's
+error before anything compiles.  Anything else raises
+:class:`ReplayUnsupported` naming the widest lane that still covers the
+shape (the ``engine='python'`` fallback) — lanes refuse, they never
 silently diverge.
 
 Transport faults (link CRC-retry bursts, port/link down windows with ECMP
@@ -69,7 +72,7 @@ from repro.core.devices import (CXLDRAMDevice, DRAMDevice, NullLink,
                                 POSTED_ACK_NS)
 from repro.core.engine import ns
 from repro.core.fabric.fabric import LINE_BYTES, Fabric, FabricAttachedDevice
-from repro.core.fabric.pool import HostPortView
+from repro.core.fabric.pool import HostPortView, ld_range_error, ld_table
 from repro.core.fabric.routing import flow_choices, flow_hash
 from repro.core.fabric.switch import ACTIVE_WINDOW_OCC
 from repro.core.replay import stack
@@ -134,6 +137,7 @@ def _extract_targets(targets: Sequence, size: int):
     views (the media half is extracted separately by :func:`_media_setup`,
     which needs the mapped address range)."""
     first = targets[0]
+    lds = None
     if isinstance(first, FabricAttachedDevice):
         fabric = first.fabric
         if not all(isinstance(t, FabricAttachedDevice)
@@ -158,6 +162,7 @@ def _extract_targets(targets: Sequence, size: int):
         nodes = pool.device_nodes
         inners = list(pool.devices)
         mapper = pool.mapper
+        lds = ld_table(targets)
     else:
         raise ReplayUnsupported(
             f"multi-host fused replay supports FabricAttachedDevice / "
@@ -234,8 +239,8 @@ def _extract_targets(targets: Sequence, size: int):
         fab_plan = None
     transport_plan = (fab_plan if fab_plan is not None
                       and (fab_plan.has_link or fab_plan.has_down) else None)
-    meta = dict(fabric=fabric, mapper=mapper, hosts=hosts, nodes=nodes,
-                inners=inners, route_count=route_count, qos=qos,
+    meta = dict(fabric=fabric, mapper=mapper, lds=lds, hosts=hosts,
+                nodes=nodes, inners=inners, route_count=route_count, qos=qos,
                 host_order=host_order, num_ports=len(pidx),
                 max_hops=max_hops, max_routes=K, num_devs=NDEV,
                 fault_plan=plan, transport_plan=transport_plan)
@@ -544,10 +549,20 @@ def _run_multi_chunk(cfg: MultiCfg, carry, p: Dict, wins: Dict, lens, base,
     return jax.lax.scan(step, carry, None, length=S, unroll=block)
 
 
-def _map_addrs(mapper, host_idx: int, addrs: np.ndarray):
-    """Host-side pool address mapping (pure per-address arithmetic)."""
+def _map_addrs(mapper, host_idx: int, addrs: np.ndarray, ld=None,
+               host: str = "", size: int = LINE_BYTES):
+    """Host-side pool address mapping (pure per-address arithmetic).
+    ``ld`` is the view's :func:`~repro.core.fabric.pool.ld_table` entry:
+    an access outside the LD raises :meth:`HostPortView.pool_address`'s
+    error, else the LD base is added before the mapper."""
     if mapper is None:
         return np.full(addrs.shape, host_idx, np.int32), addrs
+    if ld is not None:
+        out = (addrs < 0) | (addrs + size > ld["bytes"])
+        if out.any():
+            raise ld_range_error(host, ld["ld"], int(addrs[np.argmax(out)]),
+                                 size, ld["bytes"])
+        addrs = addrs + ld["base"]
     if mapper.mode == "interleave":
         frame, off = np.divmod(addrs, mapper.granularity)
         dev = (frame % mapper.num_devices).astype(np.int32)
@@ -722,21 +737,26 @@ class MultiHostReplay:
         routes = np.zeros((H, L), np.int32)
         mapper, route_count = meta["mapper"], meta["route_count"]
         tplan = meta["transport_plan"]
+        lds = meta["lds"]
         if mapper is not None:
             addrs = addrs.copy()    # mapping rewrites to device-local addrs
-        for i in range(H):
-            n = int(lens[i])
-            dev, local = _map_addrs(mapper, i, addrs[i, :n])
-            addrs[i, :n] = local
-            devs[i, :n] = dev
-            if meta["max_routes"] > 1 and tplan is None:
-                # same hash, same flow key (device-local line address) as
-                # HostPortView / FabricAttachedDevice evaluate per access
-                for d in np.unique(dev):
-                    m = dev == d
-                    routes[i, :n][m] = flow_choices(
-                        meta["hosts"][i], meta["nodes"][d],
-                        local[m] // LINE_BYTES, int(route_count[i, d]))
+        with TraceAnnotation("pool.map"):
+            for i in range(H):
+                n = int(lens[i])
+                dev, local = _map_addrs(mapper, i, addrs[i, :n],
+                                        lds[i] if lds else None,
+                                        meta["hosts"][i], size)
+                addrs[i, :n] = local
+                devs[i, :n] = dev
+                if meta["max_routes"] > 1 and tplan is None:
+                    # same hash, same flow key (device-local line address)
+                    # as HostPortView / FabricAttachedDevice evaluate per
+                    # access
+                    for d in np.unique(dev):
+                        m = dev == d
+                        routes[i, :n][m] = flow_choices(
+                            meta["hosts"][i], meta["nodes"][d],
+                            local[m] // LINE_BYTES, int(route_count[i, d]))
         stack_cfg, media_params, flash_of, n_flash = _media_setup(
             meta["inners"], size=size, outstanding=self.outstanding,
             posted_writes=self.posted_writes, n_accesses=int(lens.sum()),
