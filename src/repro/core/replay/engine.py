@@ -484,8 +484,8 @@ def _scan_chunk(cfg: StackConfig, p: Dict, carry, xs: Dict, block=1,
             ctr=ctr))
         done = out["done"]
         with jax.named_scope("telemetry"):
+            from repro.core.replay import metrics as _metrics
             if mspec is not None:
-                from repro.core.replay import metrics as _metrics
                 aux = {**aux, "q": qacc}
                 if qthr is not None:
                     aux["qthr"] = qthr
@@ -503,12 +503,8 @@ def _scan_chunk(cfg: StackConfig, p: Dict, carry, xs: Dict, block=1,
                        "first": jnp.minimum(aux["first"], issue),
                        "last": jnp.maximum(aux["last"], done),
                        "sum": aux["sum"] + (done - issue)}
-            flags = (jnp.where(out["hit"], 1, 0)
-                     | jnp.where(out["evict"], 2, 0))
-            if mspec is not None and want_lat:
-                from repro.core.replay import metrics as _metrics
-                for bit, key in _metrics.FLAG_EVENT_BITS[cfg.kind]:
-                    flags = flags | jnp.where(out[key], 1 << bit, 0)
+            flags = _metrics.event_flags(cfg.kind, out,
+                                         mspec is not None and want_lat)
         with jax.named_scope("lfb"):
             new = (slots.at[k].set(done), issue + p["issue_ov"], ctr + 1,
                    pb, st, aux)
@@ -516,7 +512,7 @@ def _scan_chunk(cfg: StackConfig, p: Dict, carry, xs: Dict, block=1,
             v = x["valid"]
             new = jax.tree.map(lambda old, nxt: jnp.where(v, nxt, old),
                                carry, new)
-        ys = ((issue, done, flags.astype(jnp.int32)) if want_lat else None)
+        ys = (issue, done, flags) if want_lat else None
         return new, ys
 
     return jax.lax.scan(step, carry, xs, unroll=block)

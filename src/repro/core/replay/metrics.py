@@ -22,19 +22,23 @@ fused run is *exactly* as observable as the interpreted run it mirrors:
 
 Parity is the contract: :func:`collect_python` builds the bundle from the
 interpreted objects, the fused assemblers from the scan outputs, and the
-golden suite pins that the two are equal on every scenario.  The fused
-side has two collection modes.  With per-access outputs
+golden suite pins that the two are equal on every scenario.  Both fused
+lanes, the single-host scan and the multi-host scan, have the same two
+collection modes, chosen by whether per-access outputs exist.  With them
 (``return_latencies=True``) the scan carries only the per-port queueing
 scalars and packs each media event into the flags column
 (:data:`FLAG_EVENT_BITS`); the histogram/window fold and counter vector
 are then pure functions of the materialized arrays, deferred to first
-bundle access — replay-time overhead is a few percent.  In streaming mode
+bundle access (:func:`bundle_single_deferred`,
+:func:`bundle_multi_deferred`).  In streaming mode
 (``return_latencies=False``) there are no per-access outputs, so the scan
 carries the whole layer: ONE scatter-add into a combined ``(rows, 4)``
 accumulator plus one counter-vector add per access — O(buckets+windows)
-state for a trace of any length.  Per-port byte/packet/occupancy totals
-are pure functions of the precomputed route choices either way, so they
-are reconstructed host-side with numpy at zero scan cost.
+state for a trace of any length (:func:`bundle_single_fused`,
+:func:`bundle_multi_fused`; the sharded multi-host lane always collects
+this way).  Per-port byte/packet/occupancy totals are pure functions of
+the precomputed route choices either way, so they are reconstructed
+host-side with numpy at zero scan cost.
 
 Histogram bucketing (shared by the numpy and jnp twins, property-tested
 equal): values below 8 index themselves (exact small-latency buckets);
@@ -201,28 +205,40 @@ def acc_update(spec: MetricsSpec, acc, *, host, dev, n_hosts: int,
     return acc.at[jnp.stack(ids)].add(rows)
 
 
-def fold_arrays(spec: MetricsSpec, issues, dones, hits, size: int):
-    """Single-host ``(hist, windows, dev_hist)`` from materialized
-    per-access arrays — the numpy twin of repeated :func:`acc_update`,
-    identical integers by construction.  When the scan already emits
-    ``(issue, done, flags)`` per access (``return_latencies=True``) the
-    histogram/window fold runs here, off the replay hot path (deferred to
-    first bundle access); the in-scan scatter is only carried in streaming
-    mode, where there are no per-access outputs to fold."""
+def fold_arrays(spec: MetricsSpec, issues, dones, hits, size: int,
+                hosts=None, n_hosts: int = 1, devs=None, n_devs: int = 1):
+    """``(hist (H,NB), windows (H,W,4), dev_hist (D,NB))`` from
+    materialized per-access arrays — the numpy twin of repeated
+    :func:`acc_update`, identical integers by construction.  ``hosts`` /
+    ``devs`` give each access's host and device index (all 0 when
+    omitted).  When the scan already emits ``(issue, done, flags)`` per
+    access (``return_latencies=True``) the histogram/window fold runs
+    here, off the replay hot path (deferred to first bundle access); the
+    in-scan scatter is only carried in streaming mode, where there are no
+    per-access outputs to fold."""
     NB, W = spec.hist_buckets, spec.num_windows
     issues = np.asarray(issues, np.int64)
     dones = np.asarray(dones, np.int64)
+    hosts = (np.zeros(issues.shape, np.int64) if hosts is None
+             else np.asarray(hosts, np.int64))
     lat = dones - issues
     b = bucket_index(lat, NB)
-    hist = np.bincount(b, minlength=NB).astype(np.int64)[None]
-    wdx = np.clip(dones // spec.window_ticks, 0, W - 1)
-    cnt = np.bincount(wdx, minlength=W).astype(np.int64)
-    windows = np.zeros((1, W, 4), np.int64)
-    windows[0, :, 0] = cnt * size
-    np.add.at(windows[0, :, 1], wdx, lat)
-    windows[0, :, 2] = cnt
-    np.add.at(windows[0, :, 3], wdx, np.asarray(hits, np.int64))
-    return hist, windows, hist.copy()
+    hist = np.bincount(hosts * NB + b, minlength=n_hosts * NB
+                       ).astype(np.int64).reshape(n_hosts, NB)
+    wdx = hosts * W + np.clip(dones // spec.window_ticks, 0, W - 1)
+    cnt = np.bincount(wdx, minlength=n_hosts * W).astype(np.int64)
+    windows = np.zeros((n_hosts * W, 4), np.int64)
+    windows[:, 0] = cnt * size
+    np.add.at(windows[:, 1], wdx, lat)
+    windows[:, 2] = cnt
+    np.add.at(windows[:, 3], wdx, np.asarray(hits, np.int64))
+    if devs is None:
+        dev_hist = hist.sum(axis=0, keepdims=True)
+    else:
+        dev_hist = np.bincount(
+            np.asarray(devs, np.int64) * NB + b, minlength=n_devs * NB
+        ).astype(np.int64).reshape(n_devs, NB)
+    return hist, windows.reshape(n_hosts, W, 4), dev_hist
 
 
 # Event booleans the scan packs into the per-access flags word when metrics
@@ -237,6 +253,19 @@ FLAG_EVENT_BITS: Dict[str, Tuple[Tuple[int, str], ...]] = {
     SSD_CACHE: ((2, "miss"), (3, "coalesce"), (4, "stall"),
                 (5, "eviction")),
 }
+
+
+def event_flags(kind: str, out, events: bool = True):
+    """The scan's per-access int32 flags word from the stack step's extras
+    dict: bit 0 hit, bit 1 evict, and with ``events`` every
+    :data:`FLAG_EVENT_BITS`\\ [kind] event at its bit."""
+    import jax.numpy as jnp
+
+    flags = jnp.where(out["hit"], 1, 0) | jnp.where(out["evict"], 2, 0)
+    if events:
+        for bit, key in FLAG_EVENT_BITS[kind]:
+            flags = flags | jnp.where(out[key], 1 << bit, 0)
+    return flags.astype(jnp.int32)
 
 
 def media_from_flags(kind: str, writes, flags) -> np.ndarray:
@@ -837,13 +866,10 @@ def bundle_single_deferred(spec: MetricsSpec, device, cfg, issues, dones,
         deferred=fold, faults=faults)
 
 
-def bundle_multi_fused(spec: MetricsSpec, meta: Dict, mcfg, acc, med,
-                       queued, qthr, flash_cnt, devs: np.ndarray,
-                       routes: np.ndarray, lens: np.ndarray, size: int,
-                       params: Dict,
-                       faults: Optional[Dict[str, int]] = None,
-                       faulted: Optional[Dict] = None) -> MetricsBundle:
-    """Assemble the bundle after a multi-host fused run.  Per-port
+def _multi_ports(meta: Dict, mcfg, queued, qthr, devs: np.ndarray,
+                 routes: np.ndarray, lens: np.ndarray, size: int,
+                 params: Dict, faulted: Optional[Dict] = None):
+    """``(ports, ecmp)`` of a multi-host fused run.  Per-port
     byte/packet/occupancy and per-host attribution are reconstructed from
     the hop tensors + route choices (numpy, exact); ``queued``/``qthr``
     are the in-scan per-port queueing and QoS-throttle accumulators.
@@ -855,12 +881,7 @@ def bundle_multi_fused(spec: MetricsSpec, meta: Dict, mcfg, acc, med,
     verbatim."""
     hosts, nodes = meta["hosts"], meta["nodes"]
     fabric = meta["fabric"]
-    H, D = len(hosts), len(nodes)
-    hist, windows, dev_hist = split_acc(spec, acc, H, D)
-    med = np.asarray(med)
-    names = MEDIA_COUNTERS[mcfg.stack.kind]
-    media = [dict(zip(names, (int(x) for x in med[d]))) for d in range(D)]
-
+    H = len(hosts)
     lens = np.asarray(lens)
     ecmp: Dict[str, List[int]] = {}
     if faulted is not None:
@@ -932,12 +953,84 @@ def bundle_multi_fused(spec: MetricsSpec, meta: Dict, mcfg, acc, med,
                     ecmp[key] = [int(c) for c in counts]
                 else:                  # same (host, node) reached twice
                     ecmp[key] = [int(a + b) for a, b in zip(prev, counts)]
+    return ports, ecmp
+
+
+def bundle_multi_fused(spec: MetricsSpec, meta: Dict, mcfg, acc, med,
+                       queued, qthr, flash_cnt, devs: np.ndarray,
+                       routes: np.ndarray, lens: np.ndarray, size: int,
+                       params: Dict,
+                       faults: Optional[Dict[str, int]] = None,
+                       faulted: Optional[Dict] = None) -> MetricsBundle:
+    """Assemble the bundle after a multi-host fused run that carried the
+    histogram/window accumulator ``acc`` and the per-device counter
+    vectors ``med`` in the scan: streaming mode (``return_latencies=
+    False``), and the sharded lane in either mode.  Runs with per-access
+    streams fold them on the host instead (:func:`bundle_multi_deferred`),
+    as the single-host lane does.  Ports and ECMP counts come from
+    :func:`_multi_ports`."""
+    hosts, nodes = meta["hosts"], meta["nodes"]
+    H, D = len(hosts), len(nodes)
+    hist, windows, dev_hist = split_acc(spec, acc, H, D)
+    med = np.asarray(med)
+    names = MEDIA_COUNTERS[mcfg.stack.kind]
+    media = [dict(zip(names, (int(x) for x in med[d]))) for d in range(D)]
+    ports, ecmp = _multi_ports(meta, mcfg, queued, qthr, devs, routes, lens,
+                               size, params, faulted)
     return MetricsBundle(
         spec=spec, hosts=list(hosts), devices=list(nodes), hist=hist,
         dev_hist=dev_hist, windows=windows, media=media,
         flash=_flash_dicts(flash_cnt), ports=ports, ecmp=ecmp,
         faults=faults,
         lds=meta.get("lds"))
+
+
+def bundle_multi_deferred(spec: MetricsSpec, meta: Dict, mcfg, who, issues,
+                          dones, flags, writes: np.ndarray, queued, qthr,
+                          flash_cnt, devs: np.ndarray, routes: np.ndarray,
+                          lens: np.ndarray, size: int, params: Dict,
+                          faults: Optional[Dict[str, int]] = None,
+                          faulted: Optional[Dict] = None) -> MetricsBundle:
+    """Assemble the bundle after a multi-host fused run with per-access
+    streams (``return_latencies=True``) — the multi-host twin of
+    :func:`bundle_single_deferred`.  The scan carries no histogram/window
+    accumulator and no counter vector; the per-step ``(who, issue, done,
+    flags)`` columns determine them, so the fold is deferred to first
+    bundle access.  Only the first ``sum(lens)`` steps count (padded steps
+    trail), and the ``k``-th step of host ``i`` is its access ``k``, on
+    device ``devs[i, k]``.  Ports and ECMP counts come from
+    :func:`_multi_ports`, as in :func:`bundle_multi_fused`."""
+    hosts, nodes = meta["hosts"], meta["nodes"]
+    H, D = len(hosts), len(nodes)
+    lens = np.asarray(lens)
+    ports, ecmp = _multi_ports(meta, mcfg, queued, qthr, devs, routes, lens,
+                               size, params, faulted)
+
+    def fold():
+        n = int(lens.sum())
+        who_v = np.asarray(who)[:n]
+        fl = np.asarray(flags)[:n]
+        devs_np, writes_np = np.asarray(devs), np.asarray(writes)
+        dev = np.zeros(n, np.int64)
+        wr = np.zeros(n, bool)
+        for i in range(H):
+            m = who_v == i
+            dev[m] = devs_np[i, :int(lens[i])]
+            wr[m] = writes_np[i, :int(lens[i])]
+        hist, windows, dev_hist = fold_arrays(
+            spec, np.asarray(issues)[:n], np.asarray(dones)[:n], fl & 1,
+            size, hosts=who_v, n_hosts=H, devs=dev, n_devs=D)
+        kind = mcfg.stack.kind
+        media = [dict(zip(MEDIA_COUNTERS[kind],
+                          (int(x) for x in media_from_flags(
+                              kind, wr[dev == d], fl[dev == d]))))
+                 for d in range(D)]
+        return hist, windows, dev_hist, media
+
+    return MetricsBundle(
+        spec=spec, hosts=list(hosts), devices=list(nodes),
+        flash=_flash_dicts(flash_cnt), ports=ports, ecmp=ecmp,
+        deferred=fold, faults=faults, lds=meta.get("lds"))
 
 
 # -------------------------------------------------- availability (faults)

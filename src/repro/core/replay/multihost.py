@@ -311,18 +311,22 @@ def _multi_init(cfg: MultiCfg, start_tick, mspec=None,
     tables, and the aux accumulators.  Built eagerly by the chunked driver
     (buffer-donated across chunk calls) and traced by :func:`_run_multi`;
     identical structure either way, which is what makes chunked multi-host
-    replay tick-identical to one-shot."""
+    replay tick-identical to one-shot.  With metrics, the histogram/window
+    accumulator and the media counter vector ride the carry only in
+    streaming mode (``want_lat=False``): with per-access streams they are
+    folded on the host from the streams and the flags column."""
     H, O = cfg.num_hosts, cfg.outstanding
     state0 = stack.init_state(cfg.stack, cfg.num_devs,
                               cfg.n_flash if cfg.n_flash else None)
     aux0 = {}
     if mspec is not None:
         from repro.core.replay import metrics as _metrics
-        aux0["acc"] = jnp.zeros(
-            (_metrics.acc_rows(mspec, H, cfg.num_devs), 4), jnp.int64)
-        aux0["med"] = jnp.zeros(
-            (cfg.num_devs, len(_metrics.MEDIA_COUNTERS[cfg.stack.kind])),
-            jnp.int64)
+        if not want_lat:
+            aux0["acc"] = jnp.zeros(
+                (_metrics.acc_rows(mspec, H, cfg.num_devs), 4), jnp.int64)
+            aux0["med"] = jnp.zeros(
+                (cfg.num_devs, len(_metrics.MEDIA_COUNTERS[cfg.stack.kind])),
+                jnp.int64)
         aux0["q"] = jnp.zeros(cfg.num_ports, jnp.int64)
         if cfg.qos:
             aux0["qthr"] = jnp.zeros(cfg.num_ports, jnp.int64)
@@ -364,7 +368,10 @@ def _make_multi_step(cfg: MultiCfg, p: Dict, lens, lookup, mspec=None,
     dict of five per-access hop columns (port / charged occupancy / after /
     on-mask / clean occupancy) — the QoS mirror paces on the *clean*
     occupancy while the physical busy-until charges retries, exactly like
-    ``SwitchPort.qos_update`` + ``transmit(retries=...)``."""
+    ``SwitchPort.qos_update`` + ``transmit(retries=...)``.  With
+    ``want_lat`` each step emits ``(host, issue, done, bad, gcs)``, and
+    the int32 flags word (:func:`~repro.core.replay.metrics.event_flags`)
+    when metrics are folded from those streams."""
     H = cfg.num_hosts
 
     def step(carry, _):
@@ -450,19 +457,22 @@ def _make_multi_step(cfg: MultiCfg, p: Dict, lens, lookup, mspec=None,
             with jax.named_scope("transport"):
                 # ack floor, data path untouched
                 done = jnp.maximum(done, floor)
+        flags = None
         with jax.named_scope("telemetry"):
             bad, gcs = stack.flash_health(st)
             if mspec is not None:
                 from repro.core.replay import metrics as _metrics
-                aux = {**aux,
-                       "acc": _metrics.acc_update(
-                           mspec, aux["acc"], host=i, dev=dev, n_hosts=H,
-                           n_devs=cfg.num_devs, issue=issue, done=done,
-                           size=size, hit=out["hit"], valid=valid),
-                       "med": aux["med"].at[dev].add(
-                           _metrics.media_increments(cfg.stack.kind, wr, out)
-                           * jnp.where(valid, 1, 0)),
-                       "q": qacc}
+                aux = {**aux, "q": qacc}
+                if "acc" in aux:
+                    aux["acc"] = _metrics.acc_update(
+                        mspec, aux["acc"], host=i, dev=dev, n_hosts=H,
+                        n_devs=cfg.num_devs, issue=issue, done=done,
+                        size=size, hit=out["hit"], valid=valid)
+                    aux["med"] = aux["med"].at[dev].add(
+                        _metrics.media_increments(cfg.stack.kind, wr, out)
+                        * jnp.where(valid, 1, 0))
+                else:
+                    flags = _metrics.event_flags(cfg.stack.kind, out)
                 if qthr is not None:
                     aux = {**aux, "qthr": qthr}
                 if "flash" in aux:
@@ -488,7 +498,11 @@ def _make_multi_step(cfg: MultiCfg, p: Dict, lens, lookup, mspec=None,
             slots = slots.at[i, k].set(done)
             now = now.at[i].set(issue + p["issue_ov"])
             idx = idx.at[i].set(idx[i] + 1)
-        ys = (i, issue, done, bad, gcs) if want_lat else None
+        ys = None
+        if want_lat:
+            ys = (i, issue, done, bad, gcs)
+            if flags is not None:
+                ys = ys + (flags,)
         return ((slots, now, idx, port_busy, ctr + 1, st, vft, last_arr,
                  aux), ys)
 
@@ -516,9 +530,18 @@ def _run_multi(cfg: MultiCfg, p: Dict, devs, addrs, writes, lens, start_tick,
     # tie lands mid-block or exactly on a seam (regression-tested).
     n_total = addrs.shape[0] * addrs.shape[1]
     carry, ys = jax.lax.scan(step, init, None, length=n_total, unroll=block)
-    who, issues, dones, bad, gcs = (ys if want_lat
-                                    else (None, None, None, None, None))
-    return who, issues, dones, bad, gcs, carry[8]
+    return _split_ys(ys, carry[8])
+
+
+def _split_ys(ys, aux):
+    """``(who, issues, dones, bad, gcs, aux)`` from the scan's per-step
+    columns; the flags column of a deferred metrics fold rides ``aux``
+    under ``"flags"``, beside the carry's accumulators."""
+    if ys is None:
+        return None, None, None, None, None, aux
+    if len(ys) > 5:
+        aux = {**aux, "flags": ys[5]}
+    return (*ys[:5], aux)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 6, 7, 8, 9),
@@ -939,12 +962,9 @@ class MultiHostReplay:
                 jnp.asarray(base), self.block_size, mspec, want_lat, size)
             if want_lat:
                 parts.append(tuple(np.asarray(y) for y in ys))
-        if want_lat:
-            who, issues, dones, bad, gcs = (
-                np.concatenate([pt[j] for pt in parts]) for j in range(5))
-        else:
-            who = issues = dones = bad = gcs = None
-        return who, issues, dones, bad, gcs, carry[8]
+        ys = (tuple(np.concatenate([pt[j] for pt in parts])
+                    for j in range(len(parts[0]))) if want_lat else None)
+        return _split_ys(ys, carry[8])
 
     def _execute(self, traces: Sequence, start_tick: int,
                  want_lat: bool = True, chunk_size=None):
@@ -986,6 +1006,8 @@ class MultiHostReplay:
                     gcs = np.asarray(gcs)
                     who, issues, dones = (np.asarray(who), np.asarray(issues),
                                           np.asarray(dones))
+                    if "flags" in aux:
+                        aux = {**aux, "flags": np.asarray(aux["flags"])}
         # padded steps (beyond sum(lens)) replay past the end and may dirty
         # the sticky flash flags — judge health at the last *valid* step
         total = int(np.asarray(lens).sum())
@@ -1017,10 +1039,19 @@ class MultiHostReplay:
                          "retired_blocks": int(rb),
                          "poisoned_reads":
                              int(self._meta.get("poisoned_reads", 0))}
-            bundle = _metrics.bundle_multi_fused(
-                mspec, self._meta, cfg, aux["acc"], aux["med"], aux["q"],
-                aux.get("qthr"), fcnt, devs, params["route"], lens, size,
-                params, faults=fdict, faulted=self._meta.get("faulted"))
+            if "acc" in aux:
+                # streaming, or a lane (the sharded one) that keeps the
+                # in-scan accumulator
+                bundle = _metrics.bundle_multi_fused(
+                    mspec, self._meta, cfg, aux["acc"], aux["med"], aux["q"],
+                    aux.get("qthr"), fcnt, devs, params["route"], lens, size,
+                    params, faults=fdict, faulted=self._meta.get("faulted"))
+            else:
+                bundle = _metrics.bundle_multi_deferred(
+                    mspec, self._meta, cfg, who, issues, dones, aux["flags"],
+                    writes, aux["q"], aux.get("qthr"), fcnt, devs,
+                    params["route"], lens, size, params, faults=fdict,
+                    faulted=self._meta.get("faulted"))
         self.last_metrics = bundle
         return who, issues, dones, lens, size, aux, bundle
 

@@ -98,16 +98,69 @@ def test_metrics_parity_gc_pressure():
     assert py.write_amplification == rp.write_amplification > 1.0
 
 
-def test_metrics_parity_multihost_qos_ecmp():
-    traces = [_trace(20 + h, n=300) for h in range(3)]
-    py = MultiHostDriver(_qos_ecmp_views(), outstanding=8,
-                         metrics=SPEC).run(traces)
-    rp = MultiHostReplay(_qos_ecmp_views(), outstanding=8,
-                         metrics=SPEC).run(traces)
+def _ld8_views():
+    """``mld8-switch``'s shape at a small size: eight hosts, each in its own
+    LD of one cached CXL-SSD behind one switch."""
+    fab = Fabric.build("single_switch", num_hosts=8, num_devices=1)
+    pool = MemoryPool(fab, {"d0": _gc_device()}, ld_bytes=750 * 4096 // 8)
+    return pool.views([f"h{i}" for i in range(8)])
+
+
+def _pool2_views():
+    """Two cached CXL-SSDs interleaved behind one switch: every host
+    reaches both, so the per-device histogram is folded too."""
+    fab = Fabric.build("single_switch", num_hosts=3, num_devices=2)
+    pool = MemoryPool(fab, {"d0": _mk("cxl-ssd-cache"),
+                            "d1": _mk("cxl-ssd-cache")})
+    return pool.views([f"h{i}" for i in range(3)])
+
+
+# (views, hosts, accesses a host, pages a host touches: 80 fit an LD)
+MULTI_VIEWS = {"qos_ecmp": (_qos_ecmp_views, 3, 300, 48),
+               "ld8": (_ld8_views, 8, 200, 80),
+               "pool2": (_pool2_views, 3, 300, 96)}
+
+
+def _fused_multi(views, traces, mode):
+    """A fused multi-host run in one collection mode: ``deferred`` (the
+    streams, folded on the host), ``chunked`` (the same, in windows) or
+    ``streaming`` (no streams: the scan carries the accumulator)."""
+    eng = MultiHostReplay(views, outstanding=8, metrics=SPEC)
+    want_lat = mode != "streaming"
+    who, issues, dones, lens, size, aux, bundle = eng._execute(
+        traces, 0, want_lat=want_lat,
+        chunk_size=97 if mode == "chunked" else None)
+    assert ("acc" in aux) == (not want_lat)
+    assert ("med" in aux) == (not want_lat)
+    res = (eng.aggregate(who, issues, dones, lens, size) if want_lat
+           else eng._aggregate_scalars(aux, lens, size))
+    return eng._attach(res, bundle)
+
+
+@pytest.mark.parametrize("mode", ["deferred", "streaming", "chunked"])
+@pytest.mark.parametrize("view", sorted(MULTI_VIEWS))
+def test_metrics_parity_multihost(view, mode):
+    """Every fused collection mode builds the interpreted driver's bundle
+    and summaries, integer for integer."""
+    make, hosts, n, pages = MULTI_VIEWS[view]
+    traces = [_trace(20 + h, n=n, pages=pages) for h in range(hosts)]
+    py = MultiHostDriver(make(), outstanding=8, metrics=SPEC).run(traces)
+    rp = _fused_multi(make(), traces, mode)
     j = _parity(py, rp)
-    assert j["ecmp"], "spine-leaf ECMP pairs must register path choices"
-    assert any(r["qos_throttle_events"] for r in j["ports"].values()), \
-        "3:1:1 weights under contention must floor someone"
+    assert py.elapsed_ticks == rp.elapsed_ticks
+    for a, b in zip(py.per_host, rp.per_host):
+        assert (a.accesses, a.elapsed_ticks, a.sum_latency_ticks,
+                a.end_tick) == (b.accesses, b.elapsed_ticks,
+                                b.sum_latency_ticks, b.end_tick)
+    if view == "qos_ecmp":
+        assert j["ecmp"], "spine-leaf ECMP pairs must register path choices"
+        assert any(r["qos_throttle_events"] for r in j["ports"].values()), \
+            "3:1:1 weights under contention must floor someone"
+    elif view == "ld8":
+        assert len(j["lds"]) == 8
+        assert j["media"][0]["evictions"] and j["flash"][0]["host_writes"]
+    else:
+        assert all(j["dev_hist"]) and all(m["misses"] for m in j["media"])
 
 
 # ------------------------------------------------ result-surface properties
@@ -153,18 +206,6 @@ def test_streaming_mode_allocates_buckets_not_trace():
     for attr in ("elapsed_ticks", "sum_latency_ticks", "end_tick",
                  "accesses"):
         assert getattr(full, attr) == getattr(slim, attr)
-
-
-def test_streaming_mode_multihost():
-    traces = [_trace(50 + h, n=200) for h in range(3)]
-    full = MultiHostReplay(_qos_ecmp_views(), metrics=SPEC).run(traces)
-    slim = MultiHostReplay(_qos_ecmp_views(), metrics=SPEC).run(
-        traces, return_latencies=False)
-    assert full.metrics.to_jsonable() == slim.metrics.to_jsonable()
-    assert full.elapsed_ticks == slim.elapsed_ticks
-    for a, b in zip(full.per_host, slim.per_host):
-        assert (a.elapsed_ticks, a.sum_latency_ticks, a.end_tick) == \
-            (b.elapsed_ticks, b.sum_latency_ticks, b.end_tick)
 
 
 # -------------------------------------------------------- fabric counters

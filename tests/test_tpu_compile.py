@@ -19,8 +19,9 @@ from jax.sharding import NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core.devices import make_device
-from repro.core.fabric import Fabric
-from repro.core.replay import MetricsSpec, ShardedMultiHostReplay, build_stack
+from repro.core.fabric import Fabric, MemoryPool
+from repro.core.replay import (MetricsSpec, MultiHostReplay,
+                               ShardedMultiHostReplay, build_stack)
 from repro.data import WorkloadSpec, host_trace_np, traces_np
 from repro.kernels.cache_sim import cache_sim_fused
 
@@ -104,16 +105,55 @@ def table1_scan(one_chip):
     return cfg, compiled
 
 
+@pytest.fixture(scope="module")
+def mld8_multi(one_chip):
+    """The multi-host scan at ``mld8.zipf``'s shapes, compiled: eight hosts,
+    each in its own 2 GiB LD of one Table I cached CXL-SSD behind one
+    switch, 4,096 accesses a host, metrics with per-access streams."""
+    from repro.core.replay.multihost import _run_multi
+
+    hosts, n = 8, 4096
+    fab = Fabric.build("single_switch", num_hosts=hosts, num_devices=1)
+    pool = MemoryPool(fab, {"d0": make_device("cxl-ssd-cache")},
+                      ld_bytes=2 * 2**30)
+    eng = MultiHostReplay(pool.views([f"h{i}" for i in range(hosts)]),
+                          metrics=MetricsSpec())
+    addrs, writes = traces_np(ZIPF, 0, hosts, n)
+    with jax.enable_x64(True):
+        cfg, params, devs, a, w, lens, size = eng.prepare_arrays(addrs,
+                                                                 writes)
+        compiled = _run_multi.lower(
+            cfg, *_shapes((params, devs, a, w, lens, np.int64(0)),
+                          one_chip),
+            1, MetricsSpec(), True, size).compile()
+    return cfg, compiled
+
+
 def test_single_host_scan_compiles_for_table1_cached_ssd(table1_scan):
     _, compiled = table1_scan
     assert _device_bytes(compiled) < HBM_BYTES
 
 
-def test_table1_scan_has_no_int64_division(table1_scan):
+def test_mld8_multi_scan_carries_no_metrics_accumulator(mld8_multi):
+    """With per-access streams the histogram, windows and media counters
+    are folded on the host: the scan carries no ``(rows, 4)`` accumulator
+    and scatters into none."""
+    from repro.core.replay.metrics import acc_rows
+
+    cfg, compiled = mld8_multi
+    hlo = compiled.as_text()
+    rows = acc_rows(MetricsSpec(), cfg.num_hosts, cfg.num_devs)
+    assert f"s64[{rows},4]" not in hlo
+    assert not re.search(r"\bscatter\(", hlo)
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("runner", ["table1_scan", "mld8_multi"])
+def test_table1_scan_has_no_int64_division(runner, request):
     """The step divides only by static powers of two, as shifts and masks:
     a TPU has no 64-bit divide, and XLA expands each int64 ``//`` or ``%``
     into some 1,700 serial scalar instructions on every step."""
-    _, compiled = table1_scan
+    _, compiled = request.getfixturevalue(runner)
     divisions = re.findall(r'op_name="[^"]*jit\((?:floor_divide|remainder)\)',
                            compiled.as_text())
     assert not divisions, f"{len(divisions)} instructions expand a division"
