@@ -35,8 +35,8 @@ bundle access (:func:`bundle_single_deferred`,
 carries the whole layer: ONE scatter-add into a combined ``(rows, 4)``
 accumulator plus one counter-vector add per access — O(buckets+windows)
 state for a trace of any length (:func:`bundle_single_fused`,
-:func:`bundle_multi_fused`; the sharded multi-host lane always collects
-this way).  Per-port byte/packet/occupancy totals are pure functions of
+:func:`bundle_multi_fused`).  The sharded multi-host lane follows the
+same rule.  Per-port byte/packet/occupancy totals are pure functions of
 the precomputed route choices either way, so they are reconstructed
 host-side with numpy at zero scan cost.
 
@@ -962,12 +962,11 @@ def bundle_multi_fused(spec: MetricsSpec, meta: Dict, mcfg, acc, med,
                        params: Dict,
                        faults: Optional[Dict[str, int]] = None,
                        faulted: Optional[Dict] = None) -> MetricsBundle:
-    """Assemble the bundle after a multi-host fused run that carried the
-    histogram/window accumulator ``acc`` and the per-device counter
-    vectors ``med`` in the scan: streaming mode (``return_latencies=
-    False``), and the sharded lane in either mode.  Runs with per-access
-    streams fold them on the host instead (:func:`bundle_multi_deferred`),
-    as the single-host lane does.  Ports and ECMP counts come from
+    """Assemble the bundle after a multi-host fused run in streaming mode
+    (``return_latencies=False``), which carried the histogram/window
+    accumulator ``acc`` and the per-device counter vectors ``med`` in the
+    scan.  Runs with per-access streams fold them on the host instead
+    (:func:`bundle_multi_deferred`).  Ports and ECMP counts come from
     :func:`_multi_ports`."""
     hosts, nodes = meta["hosts"], meta["nodes"]
     H, D = len(hosts), len(nodes)

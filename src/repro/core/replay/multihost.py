@@ -59,8 +59,9 @@ first access the python driver would fail on.
 from __future__ import annotations
 
 import functools
+from collections import namedtuple
 from dataclasses import dataclass
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -88,6 +89,9 @@ BIG = 1 << 62
 # "never arrived" sentinel for the QoS last-arrival carry: far enough below
 # zero that sentinel + activity window can never exceed a valid tick.
 NEVER = -(1 << 61)
+# the per-access transport hop columns of an active fault plan: port,
+# charged occupancy, after, on-mask, clean occupancy
+FAULT_KEYS = ("fhp", "fho", "fha", "fhon", "fhoc")
 
 
 @dataclass(frozen=True)
@@ -304,7 +308,7 @@ def _media_setup(inners: Sequence, *, size: int, outstanding: int,
 
 
 def _multi_init(cfg: MultiCfg, start_tick, mspec=None,
-                want_lat: bool = True):
+                want_lat: bool = True, local: Optional[int] = None):
     """The full multi-host carry pytree at ``start_tick`` — per-host LFB
     slots / clocks / trace cursors, shared port busy-untils, stamp counter,
     stacked media/flash state, the QoS virtual-finish / last-arrival
@@ -314,10 +318,13 @@ def _multi_init(cfg: MultiCfg, start_tick, mspec=None,
     replay tick-identical to one-shot.  With metrics, the histogram/window
     accumulator and the media counter vector ride the carry only in
     streaming mode (``want_lat=False``): with per-access streams they are
-    folded on the host from the streams and the flags column."""
+    folded on the host from the streams and the flags column.  ``local``
+    is a shard's host count: its rows, media lanes and private flashes,
+    beside global port, QoS and metrics tables."""
     H, O = cfg.num_hosts, cfg.outstanding
-    state0 = stack.init_state(cfg.stack, cfg.num_devs,
-                              cfg.n_flash if cfg.n_flash else None)
+    hosts, lanes, n_flash = ((H, cfg.num_devs, cfg.n_flash or None)
+                             if local is None else (local, local, local))
+    state0 = stack.init_state(cfg.stack, lanes, n_flash)
     aux0 = {}
     if mspec is not None:
         from repro.core.replay import metrics as _metrics
@@ -338,15 +345,15 @@ def _multi_init(cfg: MultiCfg, start_tick, mspec=None,
         if cfg.stack.faults:
             aux0["faults"] = jnp.stack(stack.fault_counters(state0))
     if not want_lat:
-        aux0["first"] = jnp.full(H, BIG, jnp.int64)
-        aux0["last"] = jnp.full(H, start_tick, jnp.int64)
-        aux0["sum"] = jnp.zeros(H, jnp.int64)
-        aux0["cnt"] = jnp.zeros(H, jnp.int64)
+        aux0["first"] = jnp.full(hosts, BIG, jnp.int64)
+        aux0["last"] = jnp.full(hosts, start_tick, jnp.int64)
+        aux0["sum"] = jnp.zeros(hosts, jnp.int64)
+        aux0["cnt"] = jnp.zeros(hosts, jnp.int64)
         aux0["bad"] = jnp.zeros((), bool)
         aux0["gcs"] = _i64(0)
-    return (jnp.full((H, O), start_tick, jnp.int64),   # per-host LFB slots
-            jnp.full(H, start_tick, jnp.int64),        # per-host issue clock
-            jnp.zeros(H, jnp.int64),                   # per-host trace index
+    return (jnp.full((hosts, O), start_tick, jnp.int64),  # LFB slots
+            jnp.full(hosts, start_tick, jnp.int64),       # issue clocks
+            jnp.zeros(hosts, jnp.int64),                  # trace indices
             jnp.zeros(cfg.num_ports, jnp.int64),       # shared port busy
             _i64(1),                                   # global stamp counter
             # stacked media/flash state: one lane per mounted device
@@ -357,25 +364,26 @@ def _multi_init(cfg: MultiCfg, start_tick, mspec=None,
             aux0)
 
 
-def _make_multi_step(cfg: MultiCfg, p: Dict, lens, lookup, mspec=None,
-                     want_lat: bool = True, size: int = 64):
-    """The per-step body of the multi-host scan, parameterized by
-    ``lookup(i, ix) -> (addr, write, dev, route, fault_cols)`` so the same
-    compiled logic can read either the full padded ``(H, L)`` trace arrays
-    (the one-shot path) or a per-host ``(H, S)`` sliding window re-based on
-    the carry's trace cursors (the chunked path).  ``fault_cols`` is
-    ``None`` on the clean path; under an active transport plan it is a
-    dict of five per-access hop columns (port / charged occupancy / after /
-    on-mask / clean occupancy) — the QoS mirror paces on the *clean*
-    occupancy while the physical busy-until charges retries, exactly like
-    ``SwitchPort.qos_update`` + ``transmit(retries=...)``.  With
-    ``want_lat`` each step emits ``(host, issue, done, bad, gcs)``, and
-    the int32 flags word (:func:`~repro.core.replay.metrics.event_flags`)
-    when metrics are folded from those streams."""
-    H = cfg.num_hosts
+# What a lane's pick hands the multi-host step: the issuing ``host``'s
+# global index (QoS tables, metrics, ``who``) and its ``row`` in the carry's
+# slots/now/idx, the LFB ``slot`` it reuses, ``issue`` and ``valid``, the
+# access (``addr``, ``write``), its global ``dev`` (DRAM timing, metrics)
+# and media/flash lanes, ``hop(h) -> (on, port, charged occupancy, clean
+# occupancy, after)``, and ``own``, the gate on the carry's commits
+# (``None`` where the lane holds every host).
+Pick = namedtuple("Pick", "host row slot issue valid addr write dev lane "
+                  "flash_lane hop own", defaults=(None,))
 
-    def step(carry, _):
-        slots, now, idx, port_busy, ctr, st, vft, last_arr, aux = carry
+
+def _local_pick(cfg: MultiCfg, p: Dict, lens, cols: Dict, base=None):
+    """The pick of a lane that holds every host: the earliest candidate
+    host (``max(own clock, oldest LFB slot)``, ties to the lowest index),
+    its access read from ``cols`` (``addr``/``wr``/``dev``, plus ``route``
+    and the :data:`FAULT_KEYS` hop columns where ``cfg`` has them): the
+    full padded ``(H, L)`` trace arrays (one-shot), or per-host ``(H, S)``
+    windows whose row ``i`` starts at trace index ``base[i]`` (chunked)."""
+
+    def pick(slots, now, idx):
         with jax.named_scope("lfb"):
             cand = jnp.where(idx < lens,
                              jnp.maximum(now, jnp.min(slots, axis=1)), BIG)
@@ -384,7 +392,53 @@ def _make_multi_step(cfg: MultiCfg, p: Dict, lens, lookup, mspec=None,
             row = slots[i]
             k = jnp.argmin(row)
             issue = jnp.maximum(now[i], row[k])
-        a, wr, dev, r, fc = lookup(i, idx[i])
+        ix = idx[i]
+        if base is not None:
+            ix = jnp.clip(ix - base[i], 0, cols["addr"].shape[1] - 1)
+        r = cols["route"][i, ix] if cfg.max_routes > 1 else 0
+        fc = ({c: cols[c][i, ix] for c in FAULT_KEYS} if cfg.fault_hops
+              else None)
+        a, wr, dev = cols["addr"][i, ix], cols["wr"][i, ix], cols["dev"][i, ix]
+
+        def hop(h):
+            if fc is not None:
+                # retries charged: occ * (1 + r); clean: the QoS entitlement
+                return (fc["fhon"][h], fc["fhp"][h], fc["fho"][h],
+                        fc["fhoc"][h], fc["fha"][h])
+            on = p["hop_on"][i, dev, r, h]
+            pi = p["hop_port"][i, dev, r, h]
+            occ = p["hop_occ"][i, dev, r, h]
+            return on, pi, occ, occ, p["hop_after"][i, dev, r, h]
+
+        return Pick(host=i, row=i, slot=k, issue=issue, valid=valid, addr=a,
+                    write=wr, dev=dev, lane=dev,
+                    flash_lane=(p["flash_of"][dev] if cfg.n_flash else 0),
+                    hop=hop)
+
+    return pick
+
+
+def _make_multi_step(cfg: MultiCfg, p: Dict, pick, mspec=None,
+                     want_lat: bool = True, size: int = 64):
+    """The per-step body of every multi-host scan.  ``pick(slots, now,
+    idx) -> Pick`` is the lane's seam: which host issues next and what its
+    access is (:func:`_local_pick`, or the sharded lane's election and
+    broadcast).  The step walks the pick's hops over the shared port
+    busy-untils — the QoS mirror pacing on the *clean* occupancy while the
+    physical busy-until charges retries, exactly like
+    ``SwitchPort.qos_update`` + ``transmit(retries=...)`` — runs
+    :func:`stack.step` on its lanes and commits the LFB, all gated by
+    ``own`` where the pick gives one.  With ``want_lat`` each step emits
+    ``(host, issue, done, bad, gcs)``, and the int32 flags word
+    (:func:`~repro.core.replay.metrics.event_flags`) when metrics are
+    folded from those streams."""
+    H = cfg.num_hosts
+
+    def step(carry, _):
+        slots, now, idx, port_busy, ctr, st, vft, last_arr, aux = carry
+        pk = pick(slots, now, idx)
+        i, issue, valid, wr = pk.host, pk.issue, pk.valid, pk.write
+        gate = valid if pk.own is None else pk.own
         posted = wr if cfg.posted_writes else jnp.zeros((), bool)
         with jax.named_scope("transport"):
             t = issue
@@ -392,18 +446,7 @@ def _make_multi_step(cfg: MultiCfg, p: Dict, lens, lookup, mspec=None,
             qacc = aux.get("q")
             qthr = aux.get("qthr")
             for h in range(cfg.max_hops):
-                if fc is not None:
-                    on = fc["on"][h]
-                    pi = fc["p"][h]
-                    occ_h = fc["o"][h]     # retries charged: occ * (1 + r)
-                    occ_c = fc["oc"][h]    # clean: the QoS entitlement
-                    after_h = fc["a"][h]
-                else:
-                    on = p["hop_on"][i, dev, r, h]
-                    pi = p["hop_port"][i, dev, r, h]
-                    occ_h = p["hop_occ"][i, dev, r, h]
-                    occ_c = occ_h
-                    after_h = p["hop_after"][i, dev, r, h]
+                on, pi, occ_h, occ_c, after_h = pk.hop(h)
                 if cfg.qos:
                     # mirror of SwitchPort.qos_update at arrival tick t
                     qon = on & p["qos_on"][pi]
@@ -439,6 +482,7 @@ def _make_multi_step(cfg: MultiCfg, p: Dict, lens, lookup, mspec=None,
                     jnp.where(on, done_h, port_busy[pi]))
                 t = jnp.where(on, done_h + after_h, t)
             t = t + p["rt_extra"]
+        dev = pk.dev
         with jax.named_scope("media"):
             if cfg.stack.kind == DRAM:
                 # DRAM-class media keeps per-device timing arrays
@@ -449,15 +493,18 @@ def _make_multi_step(cfg: MultiCfg, p: Dict, lens, lookup, mspec=None,
                          "pack": p["dev_pack"][dev]}
             else:
                 p_med = p
-        st, out = stack.step(cfg.stack, p_med, st, dict(
-            lane=dev, flash_lane=(p["flash_of"][dev] if cfg.n_flash else 0),
-            t=t, addr=a, write=wr, posted=posted, ctr=ctr))
+        access = dict(lane=pk.lane, flash_lane=pk.flash_lane, t=t,
+                      addr=pk.addr, write=wr, posted=posted, ctr=ctr)
+        if pk.own is not None:
+            access["en"] = pk.own
+        st, out = stack.step(cfg.stack, p_med, st, access)
         done = out["done"]
         if cfg.qos:
             with jax.named_scope("transport"):
                 # ack floor, data path untouched
                 done = jnp.maximum(done, floor)
         flags = None
+        row = pk.row
         with jax.named_scope("telemetry"):
             bad, gcs = stack.flash_health(st)
             if mspec is not None:
@@ -467,10 +514,10 @@ def _make_multi_step(cfg: MultiCfg, p: Dict, lens, lookup, mspec=None,
                     aux["acc"] = _metrics.acc_update(
                         mspec, aux["acc"], host=i, dev=dev, n_hosts=H,
                         n_devs=cfg.num_devs, issue=issue, done=done,
-                        size=size, hit=out["hit"], valid=valid)
+                        size=size, hit=out["hit"], valid=gate)
                     aux["med"] = aux["med"].at[dev].add(
                         _metrics.media_increments(cfg.stack.kind, wr, out)
-                        * jnp.where(valid, 1, 0))
+                        * jnp.where(gate, 1, 0))
                 else:
                     flags = _metrics.event_flags(cfg.stack.kind, out)
                 if qthr is not None:
@@ -485,19 +532,28 @@ def _make_multi_step(cfg: MultiCfg, p: Dict, lens, lookup, mspec=None,
             if not want_lat:
                 neg = _i64(-BIG)
                 aux = {**aux,
-                       "first": aux["first"].at[i].min(
-                           jnp.where(valid, issue, BIG)),
-                       "last": aux["last"].at[i].max(
-                           jnp.where(valid, done, neg)),
-                       "sum": aux["sum"].at[i].add(
-                           jnp.where(valid, done - issue, 0)),
-                       "cnt": aux["cnt"].at[i].add(jnp.where(valid, 1, 0)),
+                       "first": aux["first"].at[row].min(
+                           jnp.where(gate, issue, BIG)),
+                       "last": aux["last"].at[row].max(
+                           jnp.where(gate, done, neg)),
+                       "sum": aux["sum"].at[row].add(
+                           jnp.where(gate, done - issue, 0)),
+                       "cnt": aux["cnt"].at[row].add(jnp.where(gate, 1, 0)),
                        "bad": aux["bad"] | (bad & valid),
                        "gcs": jnp.where(valid, gcs, aux["gcs"])}
         with jax.named_scope("lfb"):
-            slots = slots.at[i, k].set(done)
-            now = now.at[i].set(issue + p["issue_ov"])
-            idx = idx.at[i].set(idx[i] + 1)
+            k = pk.slot
+            if pk.own is None:
+                slots = slots.at[row, k].set(done)
+                now = now.at[row].set(issue + p["issue_ov"])
+                idx = idx.at[row].set(idx[row] + 1)
+            else:
+                own = pk.own
+                slots = slots.at[row, k].set(
+                    jnp.where(own, done, slots[row, k]))
+                now = now.at[row].set(
+                    jnp.where(own, issue + p["issue_ov"], now[row]))
+                idx = idx.at[row].set(jnp.where(own, idx[row] + 1, idx[row]))
         ys = None
         if want_lat:
             ys = (i, issue, done, bad, gcs)
@@ -514,15 +570,9 @@ def _run_multi(cfg: MultiCfg, p: Dict, devs, addrs, writes, lens, start_tick,
                block: int = 1, mspec=None, want_lat: bool = True,
                size: int = 64):
     init = _multi_init(cfg, start_tick, mspec, want_lat)
-
-    def lookup(i, ix):
-        r = p["route"][i, ix] if cfg.max_routes > 1 else 0
-        fc = ({"p": p["fhp"][i, ix], "o": p["fho"][i, ix],
-               "a": p["fha"][i, ix], "on": p["fhon"][i, ix],
-               "oc": p["fhoc"][i, ix]} if cfg.fault_hops else None)
-        return addrs[i, ix], writes[i, ix], devs[i, ix], r, fc
-
-    step = _make_multi_step(cfg, p, lens, lookup, mspec, want_lat, size)
+    cols = {**p, "addr": addrs, "wr": writes, "dev": devs}
+    step = _make_multi_step(cfg, p, _local_pick(cfg, p, lens, cols),
+                            mspec, want_lat, size)
     # Blocked replay: `block` steps per sequential scan iteration (unroll).
     # The carry — including the per-host candidate race state (slots, now,
     # idx) — crosses block seams untouched, so the earliest-candidate-host
@@ -558,18 +608,10 @@ def _run_multi_chunk(cfg: MultiCfg, carry, p: Dict, wins: Dict, lens, base,
     the same validity gates as the one-shot path.  The carry is donated —
     threading state across an arbitrarily long trace allocates O(window),
     not O(trace)."""
-    S = wins["addr"].shape[1]
-
-    def lookup(i, ix):
-        j = jnp.clip(ix - base[i], 0, S - 1)
-        r = wins["route"][i, j] if cfg.max_routes > 1 else 0
-        fc = ({"p": wins["fhp"][i, j], "o": wins["fho"][i, j],
-               "a": wins["fha"][i, j], "on": wins["fhon"][i, j],
-               "oc": wins["fhoc"][i, j]} if cfg.fault_hops else None)
-        return wins["addr"][i, j], wins["wr"][i, j], wins["dev"][i, j], r, fc
-
-    step = _make_multi_step(cfg, p, lens, lookup, mspec, want_lat, size)
-    return jax.lax.scan(step, carry, None, length=S, unroll=block)
+    step = _make_multi_step(cfg, p, _local_pick(cfg, p, lens, wins, base),
+                            mspec, want_lat, size)
+    return jax.lax.scan(step, carry, None, length=wins["addr"].shape[1],
+                        unroll=block)
 
 
 def _map_addrs(mapper, host_idx: int, addrs: np.ndarray, ld=None,
@@ -919,9 +961,9 @@ class MultiHostReplay:
         if chunk < 1:
             raise ValueError(f"chunk_size must be >= 1, got {chunk!r}")
         routes = params["route"]
-        fkeys = ("fhp", "fho", "fha", "fhon", "fhoc")
-        fcols = ({k: params[k] for k in fkeys} if cfg.fault_hops else None)
-        skip = {"route", *fkeys}
+        fcols = ({k: params[k] for k in FAULT_KEYS} if cfg.fault_hops
+                 else None)
+        skip = {"route", *FAULT_KEYS}
         pj = jax.tree.map(jnp.asarray,
                           {k: v for k, v in params.items() if k not in skip})
         lens_np = np.asarray(lens, np.int64)
@@ -1039,9 +1081,8 @@ class MultiHostReplay:
                          "retired_blocks": int(rb),
                          "poisoned_reads":
                              int(self._meta.get("poisoned_reads", 0))}
-            if "acc" in aux:
-                # streaming, or a lane (the sharded one) that keeps the
-                # in-scan accumulator
+            if not want_lat:
+                # streaming mode: the scan carried the accumulator
                 bundle = _metrics.bundle_multi_fused(
                     mspec, self._meta, cfg, aux["acc"], aux["med"], aux["q"],
                     aux.get("qthr"), fcnt, devs, params["route"], lens, size,
@@ -1066,14 +1107,8 @@ class MultiHostReplay:
     def run(self, traces: Sequence, start_tick: int = 0,
             return_latencies: bool = True,
             chunk_size=None) -> MultiHostResult:
-        who, issues, dones, lens, size, aux, bundle = self._execute(
-            traces, start_tick, want_lat=bool(return_latencies),
-            chunk_size=chunk_size)
-        if return_latencies:
-            res = self.aggregate(who, issues, dones, lens, size, start_tick)
-        else:
-            res = self._aggregate_scalars(aux, lens, size, start_tick)
-        return self._attach(res, bundle)
+        return self._run(self.prepare(traces), start_tick, return_latencies,
+                         chunk_size)
 
     def run_arrays(self, addrs, writes, *, lens=None, size: int = 64,
                    start_tick: int = 0, return_latencies: bool = True,
@@ -1082,7 +1117,11 @@ class MultiHostReplay:
         :meth:`prepare_arrays`) — the fleet-scale entry point: synthesized
         or store-loaded traces replay without ever materializing python
         tuple lists."""
-        prep = self.prepare_arrays(addrs, writes, lens=lens, size=size)
+        return self._run(self.prepare_arrays(addrs, writes, lens=lens,
+                                             size=size),
+                         start_tick, return_latencies, chunk_size)
+
+    def _run(self, prep, start_tick, return_latencies, chunk_size):
         who, issues, dones, lens, size, aux, bundle = self._execute_prepared(
             prep, start_tick, want_lat=bool(return_latencies),
             chunk_size=chunk_size)

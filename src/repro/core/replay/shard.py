@@ -13,7 +13,11 @@ virtual-finish / last-arrival tables and the global stamp counter are
 updated identically on every shard from broadcast winner inputs, so
 replicas never diverge.
 
-Two collectives per scan step mirror the global issue order exactly:
+Every shard runs the unsharded lane's step,
+:func:`repro.core.replay.multihost._make_multi_step`, over its own carry
+(:func:`~repro.core.replay.multihost._multi_init` at ``H/D`` hosts).  This
+module supplies only the step's pick, two collectives per scan step that
+mirror the global issue order exactly:
 
 1. **winner election** — each shard races its local hosts
    (``max(own clock, oldest LFB slot)``, ties to the lowest local index)
@@ -25,19 +29,19 @@ Two collectives per scan step mirror the global issue order exactly:
    builder uses for pods).
 2. **record broadcast** — the owning shard packs the winner's access
    ``(addr, write)`` plus its per-hop transport rows (port index, charged
-   and clean occupancy, post-hop latency, on-mask) into one int64 vector,
-   zero-gated ``psum`` broadcasts it, and every shard then walks the same
-   shared-port / QoS-mirror update the unsharded lane walks — replicated
-   arithmetic on replicated state.
+   and clean occupancy, post-hop latency, on-mask) into one int64 vector
+   and zero-gated ``psum`` broadcasts it; every shard then walks the same
+   shared-port / QoS-mirror update — replicated arithmetic on replicated
+   state.
 
-The media step runs SPMD-lockstep on every shard (``lax.cond`` branches
-diverge per shard, which is fine — there is no collective inside the
-stack), with the lane *writeback* gated to the owner via
-:func:`repro.core.replay.stack.step`'s ``en`` flag; every use of the
-non-owner's garbage outputs is owner-gated before it reaches an
-accumulator.  Padded trailing steps broadcast a zero record (no port or
-QoS mutation) — valid outputs are unaffected, exactly like the unsharded
-lane's discarded trailing steps.
+The pick's ownership gate confines the media writeback
+(:func:`repro.core.replay.stack.step`'s ``en``), the LFB commit and the
+in-scan accumulators to the owning shard; the media step itself runs
+SPMD-lockstep on every shard.  After the scan the per-step ``done`` and
+flags columns are owner-gated and summed over shards, and the per-shard
+counters folded, so every output is replicated.  Metrics collect as on
+the unsharded lane: folded on the host from the streams when there are
+per-access outputs, else from the in-scan accumulator.
 
 **Certify or refuse.**  The sharded lane is tick-identical (latencies,
 MetricsBundle, fault counters) to :class:`MultiHostReplay` — and hence to
@@ -68,15 +72,12 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.core.fabric.switch import ACTIVE_WINDOW_OCC
-from repro.core.replay import stack
-from repro.core.replay.multihost import BIG, NEVER, MultiCfg, MultiHostReplay
-from repro.core.replay.spec import DRAM, ReplayUnsupported
+from repro.core.replay.multihost import (BIG, FAULT_KEYS, MultiCfg,
+                                         MultiHostReplay, Pick,
+                                         _make_multi_step, _multi_init,
+                                         _split_ys)
+from repro.core.replay.spec import ReplayUnsupported
 from repro.core.replay.stack import _i64
-
-#: params leaves that are sharded along the host axis (everything else in
-#: the params dict rides replicated)
-_FAULT_KEYS = ("fhp", "fho", "fha", "fhon", "fhoc")
 
 
 def shard_count(num_hosts: int, devices: Optional[Sequence] = None) -> int:
@@ -89,222 +90,82 @@ def shard_count(num_hosts: int, devices: Optional[Sequence] = None) -> int:
     return d
 
 
-def _body(cfg: MultiCfg, D: int, mspec, want_lat: bool, size: int,
-          block: int, start_tick, sh: Dict, rep: Dict):
-    """The per-shard program: local init, the elected-winner scan, and the
-    post-scan reductions that make every output replicated."""
-    from repro.core.replay import metrics as _metrics
-
-    H, O = cfg.num_hosts, cfg.outstanding
-    Hl = H // D
+def _elect(cfg: MultiCfg, Hl: int, sh: Dict, slots, now, idx) -> Pick:
+    """The sharded lane's pick: the global winner by election, its access
+    record broadcast from the shard that owns it."""
     L = sh["addrs"].shape[1]
     MH = cfg.max_hops
     me = jax.lax.axis_index("hosts")
-    addrs_l, writes_l, lens_l = sh["addrs"], sh["writes"], sh["lens"]
+    with jax.named_scope("lfb"):
+        cand = jnp.where(idx < sh["lens"],
+                         jnp.maximum(now, jnp.min(slots, axis=1)), BIG)
+        li0 = jnp.argmin(cand)
+    with jax.named_scope("collective"):
+        g = jax.lax.all_gather(
+            jnp.stack([cand[li0], li0.astype(jnp.int64)]), "hosts")
+    with jax.named_scope("lfb"):
+        w = jnp.argmin(g[:, 0])            # ties -> lowest shard
+        li = g[w, 1]                       # winner's local row (owner shard)
+        valid = g[w, 0] < BIG
+        own = (me == w) & valid
+        k = jnp.argmin(slots[li])
+    ix = jnp.clip(idx[li], 0, L - 1)
+    if cfg.fault_hops:
+        cols = [sh[c][li, ix] for c in ("fhon", "fhp", "fho", "fha", "fhoc")]
+    else:
+        r = sh["route"][li, ix] if cfg.max_routes > 1 else 0
+        cols = [sh[c][li, r] for c in ("hop_on", "hop_port", "hop_occ",
+                                       "hop_after", "hop_occ")]
+    rec = jnp.concatenate(
+        [jnp.stack([sh["addrs"][li, ix],
+                    sh["writes"][li, ix].astype(jnp.int64)])]
+        + [c.astype(jnp.int64) for c in cols])
+    with jax.named_scope("collective"):
+        rec = jax.lax.psum(jnp.where(own, rec, 0), "hosts")
 
-    st0 = stack.init_state(cfg.stack, Hl)
-    aux0 = {}
-    if mspec is not None:
-        # replicated-*shaped*, locally accumulated: each shard adds only
-        # its owner-steps, the post-scan psum folds them to global totals
-        aux0["acc"] = jnp.zeros(
-            (_metrics.acc_rows(mspec, H, cfg.num_devs), 4), jnp.int64)
-        aux0["med"] = jnp.zeros(
-            (cfg.num_devs, len(_metrics.MEDIA_COUNTERS[cfg.stack.kind])),
-            jnp.int64)
-        aux0["q"] = jnp.zeros(cfg.num_ports, jnp.int64)
-        if cfg.qos:
-            aux0["qthr"] = jnp.zeros(cfg.num_ports, jnp.int64)
-        fc0 = stack.flash_counters(st0)
-        if fc0 is not None:
-            aux0["flash"] = fc0                     # local (Hl, 5) snapshot
-        if cfg.stack.faults:
-            aux0["faults"] = jnp.stack(stack.fault_counters(st0))
-    if not want_lat:
-        aux0["first"] = jnp.full(Hl, BIG, jnp.int64)
-        aux0["last"] = jnp.full(Hl, start_tick, jnp.int64)
-        aux0["sum"] = jnp.zeros(Hl, jnp.int64)
-        aux0["cnt"] = jnp.zeros(Hl, jnp.int64)
-        aux0["bad"] = jnp.zeros((), bool)
-        aux0["gcs"] = _i64(0)
-    init = (jnp.full((Hl, O), start_tick, jnp.int64),
-            jnp.full(Hl, start_tick, jnp.int64),
-            jnp.zeros(Hl, jnp.int64),
-            jnp.zeros(cfg.num_ports, jnp.int64),
-            _i64(1),
-            st0,
-            jnp.zeros((cfg.num_ports, H), jnp.int64),
-            jnp.full((cfg.num_ports, H), NEVER, jnp.int64),
-            aux0)
+    def hop(h):
+        on, pi, occ, after, occ_c = (rec[2 + c * MH + h] for c in range(5))
+        return on > 0, pi, occ, occ_c, after
 
-    def step(carry, _):
-        slots, now, idx, port_busy, ctr, st, vft, last_arr, aux = carry
-        # -- collective 1: winner election (global lowest-(tick, index))
-        with jax.named_scope("lfb"):
-            cand = jnp.where(idx < lens_l,
-                             jnp.maximum(now, jnp.min(slots, axis=1)), BIG)
-            li0 = jnp.argmin(cand)
-        with jax.named_scope("collective"):
-            g = jax.lax.all_gather(
-                jnp.stack([cand[li0], li0.astype(jnp.int64)]), "hosts")
-        with jax.named_scope("lfb"):
-            w = jnp.argmin(g[:, 0])        # ties -> lowest shard
-            li = g[w, 1]                   # winner's local lane (owner shard)
-            issue = g[w, 0]                # == max(now, min slot) when valid
-            valid = issue < BIG
-            am = me == w
-            gate = am & valid
-            i_glob = w * Hl + li
-        # -- collective 2: the owner's access record, broadcast to all
-        ix = jnp.clip(idx[li], 0, L - 1)
-        a0 = addrs_l[li, ix]
-        w0 = writes_l[li, ix].astype(jnp.int64)
-        if cfg.fault_hops:
-            on_v = sh["fhon"][li, ix].astype(jnp.int64)
-            pi_v = sh["fhp"][li, ix].astype(jnp.int64)
-            occ_v = sh["fho"][li, ix]
-            aft_v = sh["fha"][li, ix]
-            occc_v = sh["fhoc"][li, ix]
-        else:
-            r = sh["route"][li, ix] if cfg.max_routes > 1 else 0
-            on_v = sh["hop_on"][li, r].astype(jnp.int64)
-            pi_v = sh["hop_port"][li, r].astype(jnp.int64)
-            occ_v = sh["hop_occ"][li, r]
-            aft_v = sh["hop_after"][li, r]
-            occc_v = occ_v
-        rec = jnp.concatenate([jnp.stack([a0, w0]), on_v, pi_v, occ_v,
-                               aft_v, occc_v])
-        with jax.named_scope("collective"):
-            rec = jax.lax.psum(jnp.where(gate, rec, 0), "hosts")
-        a = rec[0]
-        wr = rec[1] > 0
-        posted = wr if cfg.posted_writes else jnp.zeros((), bool)
-        # -- replicated transport walk + QoS mirror (identical on every
-        # shard: broadcast inputs, replicated state — byte-for-byte the
-        # unsharded loop, reading the record instead of the lookup)
-        with jax.named_scope("transport"):
-            t = jnp.where(valid, issue, _i64(0))
-            floor = _i64(0)
-            qacc = aux.get("q")
-            qthr = aux.get("qthr")
-            for h in range(MH):
-                on = rec[2 + h] > 0
-                pi = rec[2 + MH + h]
-                occ_h = rec[2 + 2 * MH + h]
-                aft_h = rec[2 + 3 * MH + h]
-                occ_c = rec[2 + 4 * MH + h]
-                if cfg.qos:
-                    qon = on & rep["qos_on"][pi]
-                    prev = vft[pi, i_glob]
-                    win = occ_c * ACTIVE_WINDOW_OCC
-                    w_active = jnp.float64(0.0)
-                    # sorted-name order, like the dict walk
-                    for j in cfg.host_order:
-                        member = (j == i_glob) | (last_arr[pi, j] + win > t)
-                        w_active = w_active + jnp.where(
-                            member, rep["qos_w"][pi, j], 0.0)
-                    pace = (occ_c.astype(jnp.float64)
-                            * (w_active / rep["qos_w"][pi, i_glob])
-                            ).astype(jnp.int64)
-                    floor = jnp.maximum(
-                        floor, jnp.where(qon & (prev > t), prev + pace, 0))
-                    vft = vft.at[pi, i_glob].set(
-                        jnp.where(qon, jnp.maximum(prev, t) + pace, prev))
-                    last_arr = last_arr.at[pi, i_glob].set(
-                        jnp.where(qon, t, last_arr[pi, i_glob]))
-                    if qthr is not None:
-                        qthr = qthr.at[pi].add(
-                            jnp.where(qon & (prev > t) & valid, 1, 0))
-                start = jnp.maximum(t, port_busy[pi])
-                if qacc is not None:
-                    qacc = qacc.at[pi].add(
-                        jnp.where(on & valid, start - t, 0))
-                done_h = start + occ_h
-                port_busy = port_busy.at[pi].set(
-                    jnp.where(on, done_h, port_busy[pi]))
-                t = jnp.where(on, done_h + aft_h, t)
-            t = t + rep["rt_extra"]
-        # -- SPMD media step: every shard runs it on lane `li` of its own
-        # local state, only the owner commits (en gate); non-owner outputs
-        # are garbage and every use below is owner-gated
-        with jax.named_scope("media"):
-            if cfg.stack.kind == DRAM:
-                p_med = {"occ": rep["dev_occ"][i_glob],
-                         "load": rep["dev_load"][i_glob],
-                         "pack": rep["dev_pack"][i_glob]}
-            else:
-                p_med = rep
-        st, out = stack.step(cfg.stack, p_med, st, dict(
-            lane=li, flash_lane=li, t=t, addr=a, write=wr, posted=posted,
-            ctr=ctr, en=gate))
-        done = out["done"]
-        if cfg.qos:
-            with jax.named_scope("transport"):
-                done = jnp.maximum(done, floor)
-        with jax.named_scope("telemetry"):
-            bad_l, gcs_l = stack.flash_health(st)
-            if mspec is not None:
-                aux = {**aux,
-                       "acc": _metrics.acc_update(
-                           mspec, aux["acc"], host=i_glob, dev=i_glob,
-                           n_hosts=H, n_devs=cfg.num_devs, issue=issue,
-                           done=done,
-                           size=size, hit=out["hit"], valid=gate),
-                       "med": aux["med"].at[i_glob].add(
-                           _metrics.media_increments(cfg.stack.kind, wr, out)
-                           * jnp.where(gate, 1, 0)),
-                       "q": qacc}
-                if qthr is not None:
-                    aux = {**aux, "qthr": qthr}
-                if "flash" in aux:
-                    aux = {**aux, "flash": jnp.where(
-                        valid, stack.flash_counters(st), aux["flash"])}
-                if "faults" in aux:
-                    aux = {**aux, "faults": jnp.where(
-                        valid, jnp.stack(stack.fault_counters(st)),
-                        aux["faults"])}
-            if not want_lat:
-                aux = {**aux,
-                       "first": aux["first"].at[li].min(
-                           jnp.where(gate, issue, BIG)),
-                       "last": aux["last"].at[li].max(
-                           jnp.where(gate, done, _i64(-BIG))),
-                       "sum": aux["sum"].at[li].add(
-                           jnp.where(gate, done - issue, 0)),
-                       "cnt": aux["cnt"].at[li].add(jnp.where(gate, 1, 0)),
-                       "bad": aux["bad"] | (bad_l & valid),
-                       "gcs": jnp.where(valid, gcs_l, aux["gcs"])}
-        with jax.named_scope("lfb"):
-            k = jnp.argmin(slots[li])
-            slots = slots.at[li, k].set(jnp.where(gate, done, slots[li, k]))
-            now = now.at[li].set(
-                jnp.where(gate, issue + rep["issue_ov"], now[li]))
-            idx = idx.at[li].set(jnp.where(gate, idx[li] + 1, idx[li]))
-        ys = ((i_glob, issue, jnp.where(gate, done, 0),
-               jnp.where(bad_l, 1, 0), gcs_l) if want_lat else None)
-        return ((slots, now, idx, port_busy, ctr + 1, st, vft, last_arr,
-                 aux), ys)
+    host = w * Hl + li
+    return Pick(host=host, row=li, slot=k,
+                issue=jnp.where(valid, g[w, 0], _i64(0)), valid=valid,
+                addr=rec[0], write=rec[1] > 0, dev=host, lane=li,
+                flash_lane=li, hop=hop, own=own)
 
-    carry, ys = jax.lax.scan(step, init, None, length=H * L, unroll=block)
-    aux = carry[8]
-    # -- post-scan reductions: every returned leaf becomes replicated
+
+def _body(cfg: MultiCfg, D: int, mspec, want_lat: bool, size: int,
+          block: int, start_tick, sh: Dict, rep: Dict):
+    """The per-shard program: the shared multi-host step over this shard's
+    carry, and the post-scan reductions that make every output
+    replicated."""
+    H = cfg.num_hosts
+    Hl = H // D
+    init = _multi_init(cfg, start_tick, mspec, want_lat, local=Hl)
+    step = _make_multi_step(cfg, rep, functools.partial(_elect, cfg, Hl, sh),
+                            mspec, want_lat, size)
+    carry, ys = jax.lax.scan(step, init, None,
+                             length=H * sh["addrs"].shape[1], unroll=block)
+    who, issues, dones, bad, gcs, aux = _split_ys(ys, carry[8])
     with jax.named_scope("collective"):
         if want_lat:
-            who, issues, d_gated, bad_i, gcs_loc = ys
-            dones = jax.lax.psum(d_gated, "hosts")
-            bad = jax.lax.psum(bad_i, "hosts") > 0
-            gcs = jax.lax.psum(gcs_loc, "hosts")
-        else:
-            who = issues = dones = bad = gcs = None
-        if mspec is not None:
+            lo = jax.lax.axis_index("hosts") * Hl
+            mine = (who >= lo) & (who < lo + Hl)
+            dones = jax.lax.psum(jnp.where(mine, dones, 0), "hosts")
+            if "flags" in aux:
+                aux = {**aux, "flags": jax.lax.psum(
+                    jnp.where(mine, aux["flags"], 0), "hosts")}
+            bad = jax.lax.psum(jnp.where(bad, 1, 0), "hosts") > 0
+            gcs = jax.lax.psum(gcs, "hosts")
+        if "acc" in aux:
             aux = {**aux,
                    "acc": jax.lax.psum(aux["acc"], "hosts"),
                    "med": jax.lax.psum(aux["med"], "hosts")}
-            if "flash" in aux:
-                aux = {**aux, "flash": jax.lax.all_gather(
-                    aux["flash"], "hosts").reshape(H, -1)}
-            if "faults" in aux:
-                aux = {**aux, "faults": jax.lax.psum(aux["faults"], "hosts")}
+        if "flash" in aux:
+            aux = {**aux, "flash": jax.lax.all_gather(
+                aux["flash"], "hosts").reshape(H, -1)}
+        if "faults" in aux:
+            aux = {**aux, "faults": jax.lax.psum(aux["faults"], "hosts")}
         if not want_lat:
             gathered = {k: jax.lax.all_gather(aux[k], "hosts").reshape(H)
                         for k in ("first", "last", "sum", "cnt")}
@@ -357,7 +218,7 @@ class ShardedMultiHostReplay(MultiHostReplay):
               "writes": np.ascontiguousarray(writes),
               "lens": np.asarray(lens, np.int64)}
         if cfg.fault_hops:
-            for k in _FAULT_KEYS:
+            for k in FAULT_KEYS:
                 sh[k] = params[k]
         else:
             diag = np.arange(H)
@@ -366,7 +227,7 @@ class ShardedMultiHostReplay(MultiHostReplay):
             if cfg.max_routes > 1:
                 sh["route"] = params["route"]
         skip = {"hop_port", "hop_occ", "hop_after", "hop_on", "route",
-                "flash_of", *_FAULT_KEYS}
+                "flash_of", *FAULT_KEYS}
         rep = {k: v for k, v in params.items() if k not in skip}
         return sh, rep
 

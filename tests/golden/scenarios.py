@@ -393,9 +393,9 @@ def run_python_metrics(name: str):
 
 
 def run_scan_metrics(name: str):
-    """Fused-lane metrics bundle (JSON form): in-scan accumulation must
-    match the interpreted stats dicts exactly (``fleet-*`` through the
-    sharded lane — its psum-folded accumulators included)."""
+    """Fused-lane metrics bundle (JSON form): it must match the
+    interpreted stats dicts exactly (``fleet-*`` through the sharded
+    lane)."""
     from repro.core.replay import (MultiHostReplay, ReplayEngine,
                                    ShardedMultiHostReplay)
     from repro.core.replay.metrics import MetricsSpec

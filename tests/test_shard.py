@@ -96,21 +96,88 @@ def test_sharded_tick_identical_other_media(name):
     _assert_identical(py, ru, lat_u, rs, lat_s)
 
 
-def test_sharded_metrics_bundle_identical():
-    """The psum-folded in-scan accumulators render the exact same
-    MetricsBundle JSON as the unsharded lane AND the interpreted driver
-    (histograms, windows, port/QoS telemetry, media counters)."""
+@pytest.mark.parametrize("return_latencies", [True, False],
+                         ids=["streams", "streaming"])
+def test_sharded_metrics_bundle_identical(return_latencies):
+    """The sharded lane renders the exact same MetricsBundle JSON as the
+    unsharded lane AND the interpreted driver (histograms, windows,
+    port/QoS telemetry, media counters) in both collection modes: folded
+    on the host from the owner-gated streams, or from the psum-folded
+    in-scan accumulator."""
     nh = 4
     traces = _traces(nh, kind="zipfian")
     py = MultiHostDriver(_mounts(nh, qos=True), outstanding=OUTSTANDING,
                          metrics=MetricsSpec()).run(traces)
     ru = MultiHostReplay(_mounts(nh, qos=True), outstanding=OUTSTANDING,
-                         metrics=MetricsSpec()).run(traces)
+                         metrics=MetricsSpec()).run(
+        traces, return_latencies=return_latencies)
     rs = ShardedMultiHostReplay(_mounts(nh, qos=True),
                                 outstanding=OUTSTANDING,
-                                metrics=MetricsSpec()).run(traces)
+                                metrics=MetricsSpec()).run(
+        traces, return_latencies=return_latencies)
     assert py.metrics.to_jsonable() == ru.metrics.to_jsonable() \
         == rs.metrics.to_jsonable()
+
+
+# The sharded lane at four shards, in a child process that forces four CPU
+# devices: both lanes, both collection modes, one JSON line on stdout.
+_FOUR_SHARDS = r"""
+import json, sys
+import numpy as np
+sys.path[:0] = sys.argv[1:]
+from test_shard import _mounts, _traces, _tup, OUTSTANDING
+from repro.core.replay import (MetricsSpec, MultiHostReplay,
+                               ShardedMultiHostReplay)
+
+nh = 8
+traces = _traces(nh, n=96, kind="zipfian")
+out = {}
+for lat in (True, False):
+    for name, cls in (("unsharded", MultiHostReplay),
+                      ("sharded", ShardedMultiHostReplay)):
+        eng = cls(_mounts(nh, "cxl-ssd-cache", qos=True),
+                  outstanding=OUTSTANDING, metrics=MetricsSpec())
+        if lat:
+            res, lats = eng.run_recorded(traces)
+            lats = [np.asarray(x).tolist() for x in lats]
+        else:
+            res, lats = eng.run(traces, return_latencies=False), None
+        out[f"{name}/{lat}"] = {
+            "summary": [_tup(r) for r in res.per_host]
+                       + [res.elapsed_ticks],
+            "latency": lats, "metrics": res.metrics.to_jsonable(),
+            "mesh": getattr(eng, "last_mesh", None)}
+print(json.dumps(out))
+"""
+
+
+def test_sharded_equals_unsharded_across_four_shards():
+    """Real shard boundaries in the default tier: the election's
+    all_gather and the record broadcast cross four devices, and the
+    latency streams and MetricsBundle equal the unsharded lane's with and
+    without per-access outputs (8 cached CXL-SSD hosts, QoS, ECMP)."""
+    import json
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=4"}
+    paths = [str(Path(__file__).parent)] + [p for p in sys.path if p]
+    proc = subprocess.run([sys.executable, "-c", _FOUR_SHARDS, *paths],
+                          env=env, capture_output=True, text=True,
+                          timeout=300)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    for lat in (True, False):
+        u, s = out[f"unsharded/{lat}"], out[f"sharded/{lat}"]
+        assert s["mesh"] == {"device_count": 4, "hosts_per_device": 2}
+        assert u["summary"] == s["summary"]
+        assert u["latency"] == s["latency"]
+        assert u["metrics"] == s["metrics"]
+    assert out["sharded/True"]["latency"] is not None
+    assert out["sharded/True"]["metrics"] == out["sharded/False"]["metrics"]
 
 
 def test_sharded_qos_tick_identical():

@@ -129,37 +129,11 @@ def mld8_multi(one_chip):
     return cfg, compiled
 
 
-def test_single_host_scan_compiles_for_table1_cached_ssd(table1_scan):
-    _, compiled = table1_scan
-    assert _device_bytes(compiled) < HBM_BYTES
-
-
-def test_mld8_multi_scan_carries_no_metrics_accumulator(mld8_multi):
-    """With per-access streams the histogram, windows and media counters
-    are folded on the host: the scan carries no ``(rows, 4)`` accumulator
-    and scatters into none."""
-    from repro.core.replay.metrics import acc_rows
-
-    cfg, compiled = mld8_multi
-    hlo = compiled.as_text()
-    rows = acc_rows(MetricsSpec(), cfg.num_hosts, cfg.num_devs)
-    assert f"s64[{rows},4]" not in hlo
-    assert not re.search(r"\bscatter\(", hlo)
-    assert _device_bytes(compiled) < HBM_BYTES
-
-
-@pytest.mark.parametrize("runner", ["table1_scan", "mld8_multi"])
-def test_table1_scan_has_no_int64_division(runner, request):
-    """The step divides only by static powers of two, as shifts and masks:
-    a TPU has no 64-bit divide, and XLA expands each int64 ``//`` or ``%``
-    into some 1,700 serial scalar instructions on every step."""
-    _, compiled = request.getfixturevalue(runner)
-    divisions = re.findall(r'op_name="[^"]*jit\((?:floor_divide|remainder)\)',
-                           compiled.as_text())
-    assert not divisions, f"{len(divisions)} instructions expand a division"
-
-
-def test_sharded_fleet_runner_compiles_on_four_chip_mesh(topo):
+@pytest.fixture(scope="module")
+def fleet_sharded(topo):
+    """The sharded fleet runner on the described 2x2 mesh, compiled: eight
+    cached CXL-SSD hosts on a two-pod ECMP fabric, two a chip, 2,048
+    accesses a host, metrics with per-access streams."""
     from repro.core.replay.shard import _build_runner
 
     hosts, n = 8, 2048
@@ -179,6 +153,45 @@ def test_sharded_fleet_runner_compiles_on_four_chip_mesh(topo):
                                  sharding=NamedSharding(mesh, P())),
             _shapes(sh, NamedSharding(mesh, P("hosts"))),
             _shapes(rep, NamedSharding(mesh, P()))).compile()
+    return cfg, compiled
+
+
+def test_single_host_scan_compiles_for_table1_cached_ssd(table1_scan):
+    _, compiled = table1_scan
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("runner", ["mld8_multi", "fleet_sharded"])
+def test_mld8_multi_scan_carries_no_metrics_accumulator(runner, request):
+    """With per-access streams the histogram, windows and media counters
+    are folded on the host, on the unsharded and the sharded lane alike:
+    the scan carries no ``(rows, 4)`` accumulator and scatters into
+    none."""
+    from repro.core.replay.metrics import acc_rows
+
+    cfg, compiled = request.getfixturevalue(runner)
+    hlo = compiled.as_text()
+    rows = acc_rows(MetricsSpec(), cfg.num_hosts, cfg.num_devs)
+    assert f"s64[{rows},4]" not in hlo
+    assert not re.search(r"\bscatter\(", hlo)
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("runner",
+                         ["table1_scan", "mld8_multi", "fleet_sharded"])
+def test_table1_scan_has_no_int64_division(runner, request):
+    """The step divides only by static powers of two, as shifts and masks:
+    a TPU has no 64-bit divide, and XLA expands each int64 ``//`` or ``%``
+    into some 1,700 serial scalar instructions on every step."""
+    _, compiled = request.getfixturevalue(runner)
+    divisions = re.findall(r'op_name="[^"]*jit\((?:floor_divide|remainder)\)',
+                           compiled.as_text())
+    assert not divisions, f"{len(divisions)} instructions expand a division"
+
+
+def test_sharded_fleet_runner_compiles_on_four_chip_mesh(topo,
+                                                        fleet_sharded):
+    _, compiled = fleet_sharded
     hlo = compiled.as_text()
     assert len(topo.devices) == 4
     assert "all-reduce" in hlo or "all-gather" in hlo
