@@ -79,6 +79,7 @@ from repro.core.replay.spec import (
     ReplayUnsupported,
     StackConfig,
     build_stack,
+    compact_pages,
     trace_to_arrays,
     validate_block_size,
 )
@@ -848,6 +849,9 @@ class ReplayEngine:
         if plan is not None and plan.has_poison:
             poisoned = plan.poisoned_np(
                 0, np.arange(addrs.size, dtype=np.int64), writes)
+        # the device sees dense page ids; ``addrs`` stays real for routes
+        # and the metrics fold
+        cfg, dev_addrs = compact_pages(cfg, addrs)
         with enable_x64(True):
             with TraceAnnotation("replay.put"):
                 pj = jax.tree.map(jnp.asarray, params)
@@ -861,7 +865,7 @@ class ReplayEngine:
                 def _feed():
                     for lo in range(0, n, chunk):
                         hi = min(lo + chunk, n)
-                        d = {"addr": addrs[lo:hi], "wr": writes[lo:hi]}
+                        d = {"addr": dev_addrs[lo:hi], "wr": writes[lo:hi]}
                         if cfg.fault_hops:
                             for key, c in zip(("hp", "ho", "ha", "hon",
                                                "hoc"), fcols):
@@ -876,7 +880,7 @@ class ReplayEngine:
                         self.block_size, mspec, want_lat, size)
             else:
                 with TraceAnnotation("replay.put"):
-                    xs = [jnp.asarray(addrs), jnp.asarray(writes)]
+                    xs = [jnp.asarray(dev_addrs), jnp.asarray(writes)]
                     if cfg.fault_hops:
                         runner = _run_stack_faulted
                         xs.append(tuple(jnp.asarray(c) for c in fcols))

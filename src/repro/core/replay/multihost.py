@@ -58,6 +58,7 @@ first access the python driver would fail on.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from collections import namedtuple
 from dataclasses import dataclass
@@ -78,7 +79,8 @@ from repro.core.fabric.routing import flow_choices, flow_hash
 from repro.core.fabric.switch import ACTIVE_WINDOW_OCC
 from repro.core.replay import stack
 from repro.core.replay.spec import (DRAM, ReplayUnsupported, StackConfig,
-                                    media_stack, trace_to_arrays,
+                                    compact_pages, media_stack,
+                                    trace_to_arrays,
                                     validate_block_size,
                                     validate_trace_columns)
 from repro.core.replay.stack import MAX_ACCESSES, _i64
@@ -639,6 +641,19 @@ def _map_addrs(mapper, host_idx: int, addrs: np.ndarray, ld=None,
     return dev64.astype(np.int32), local
 
 
+def _compact_prepared(prep):
+    """A prepared tuple as the device runs it: the address column in dense
+    page ids over every host's valid entries and the stack config with the
+    map sized to them (:func:`~repro.core.replay.spec.compact_pages`).
+    :meth:`MultiHostReplay.prepare_arrays` keeps the device-local
+    addresses and the span-sized map, for callers that read them."""
+    cfg, params, devs, addrs, writes, lens, size = prep
+    valid = np.arange(addrs.shape[1]) < np.asarray(lens)[:, None]
+    st, addrs = compact_pages(cfg.stack, addrs, valid)
+    return (dataclasses.replace(cfg, stack=st), params, devs, addrs, writes,
+            lens, size)
+
+
 # -------------------------------------------------- transport fault columns
 def _fault_cols_multi(meta: Dict, plan, addrs: np.ndarray,
                       lens: np.ndarray, size: int):
@@ -1032,7 +1047,7 @@ class MultiHostReplay:
 
     def _execute_prepared(self, prep, start_tick: int,
                           want_lat: bool = True, chunk_size=None):
-        cfg, params, devs, addrs, writes, lens, size = prep
+        cfg, params, devs, addrs, writes, lens, size = _compact_prepared(prep)
         if cfg.qos and start_tick < 0:
             raise ReplayUnsupported(
                 "QoS replay needs start_tick >= 0 (the virtual-clock and "

@@ -27,10 +27,12 @@ greedy collection the Python FTL does (see :mod:`repro.core.replay.stack`).
 
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass
 from typing import Dict, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.devices import (
     CachedCXLSSDDevice,
@@ -107,7 +109,8 @@ class StackConfig:
     dies_per_channel: int = 0
     pages_per_block: int = 0
     buf_entries: int = 0         # SSD_BUF page registers
-    num_pages: int = 0           # l2p table size (trace footprint, pow2)
+    num_pages: int = 0           # l2p table size (pow2): the address span,
+                                 # or the trace's length after compact_pages
     # greedy-GC lane (selected when the trace could outrun log-append
     # headroom; see repro.core.replay.stack)
     gc: bool = False
@@ -409,8 +412,7 @@ def _media_config(inner: MemDevice, common: Dict, params: Dict, *,
         ftl = inner.hil.ftl
         if ftl.num_blocks * ftl.pages_per_block >= (1 << 31):
             raise ReplayUnsupported("physical page numbers overflow int32")
-    n_pages = max(1, max_addr // page_bytes + 1)
-    n_pages = 1 << (n_pages - 1).bit_length()   # pow2: stable compilations
+    n_pages = _pow2(max_addr // page_bytes + 1)   # stable compilations
 
     if isinstance(inner, CXLSSDDevice):
         from repro.core.cache.policies import LRUPolicy
@@ -460,6 +462,44 @@ def _media_config(inner: MemDevice, common: Dict, params: Dict, *,
 
     raise ReplayUnsupported(
         f"no fused model for {type(inner).__name__}; use engine='python'")
+
+
+def _pow2(n: int) -> int:
+    return 1 << (max(1, int(n)) - 1).bit_length()
+
+
+def compact_pages(cfg: StackConfig, addrs, valid=None
+                  ) -> Tuple[StackConfig, np.ndarray]:
+    """The device copy of an address column, with the FTL map sized by
+    the pages the column can touch instead of by its address span.
+
+    Each distinct page of the ``valid`` entries (default: all) gets a dense
+    id in page order (``np.unique`` sorts), so every equality and order
+    between pages is kept; each address keeps its offset in the page, and
+    entries outside ``valid`` become 0.  The map then holds
+    ``min(cfg.num_pages, pow2(addrs.size))`` entries.  Only the stacks
+    whose device side compares pages and never computes with one take it:
+    the page-register buffer and the lru/fifo cache.  A direct-mapped
+    cache picks its frame by ``page % cap``; those stacks, flash-less
+    media, and a column whose map would not shrink come back unchanged.
+    Call it after every host-side use of the real addresses (pool mapping,
+    ECMP flow hashes, fault hop columns, the metrics fold's inputs)."""
+    addrs = np.asarray(addrs, np.int64)
+    if not (cfg.kind == SSD_BUF
+            or (cfg.kind == SSD_CACHE and cfg.cache_assoc)):
+        return cfg, addrs
+    n_pages = min(cfg.num_pages, _pow2(addrs.size))
+    if n_pages == cfg.num_pages:
+        return cfg, addrs
+    with TraceAnnotation("replay.pages", map_pages=n_pages,
+                         span_pages=cfg.num_pages):
+        live = (np.ones(addrs.shape, bool) if valid is None
+                else np.asarray(valid, bool))
+        page, off = np.divmod(addrs[live], cfg.page_bytes)
+        ids = np.unique(page, return_inverse=True)[1]
+        out = np.zeros_like(addrs)
+        out[live] = ids * cfg.page_bytes + off
+    return dataclasses.replace(cfg, num_pages=n_pages), out
 
 
 def require_metrics_lane(engine: str) -> None:

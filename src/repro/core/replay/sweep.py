@@ -42,8 +42,10 @@ from jax.profiler import TraceAnnotation
 from repro.core.replay import stack
 from repro.core.replay.engine import _scan_stack
 from repro.core.replay.metrics import availability_series
-from repro.core.replay.multihost import MultiHostReplay, _run_multi
-from repro.core.replay.spec import SSD_CACHE, ReplayUnsupported, build_stack
+from repro.core.replay.multihost import (MultiHostReplay, _compact_prepared,
+                                         _run_multi)
+from repro.core.replay.spec import (SSD_CACHE, ReplayUnsupported, build_stack,
+                                    compact_pages)
 from repro.core.replay.stack import MAX_ACCESSES, PAGE_FIELD, _i64
 from repro.core.workloads.driver import MultiHostResult
 from repro.obs import scopes
@@ -147,6 +149,7 @@ def cache_design_sweep(device, addrs, writes, *,
         batched.add(name)
 
     trace_ax = 0 if addrs.ndim == 2 else None
+    cfg, addrs = compact_pages(cfg, addrs)
     with enable_x64(True):
         with TraceAnnotation("replay.put"):
             pj = {k: jnp.asarray(v) for k, v in params.items()}
@@ -218,7 +221,8 @@ def host_count_sweep(targets: Sequence, traces: Sequence,
                                      issue_overhead_ns=issue_overhead_ns,
                                      posted_writes=posted_writes,
                                      devices=devices)
-        cfg, params, devs, addrs, writes, lens, size = eng.prepare(traces)
+        cfg, params, devs, addrs, writes, lens, size = _compact_prepared(
+            eng.prepare(traces))
         out: List[MultiHostResult] = []
         with enable_x64(True):
             for h in host_counts:
@@ -241,7 +245,8 @@ def host_count_sweep(targets: Sequence, traces: Sequence,
     eng = MultiHostReplay(targets, outstanding=outstanding,
                           issue_overhead_ns=issue_overhead_ns,
                           posted_writes=posted_writes)
-    cfg, params, devs, addrs, writes, lens, size = eng.prepare(traces)
+    cfg, params, devs, addrs, writes, lens, size = _compact_prepared(
+        eng.prepare(traces))
     if info is not None:
         info.update({"sharded": False, "device_count": 1,
                      "hosts_per_device": int(lens.size)})
@@ -302,7 +307,8 @@ def fault_seed_sweep(make_targets, traces: Sequence, seeds: Sequence[int],
         eng = MultiHostReplay(make_targets(s), outstanding=outstanding,
                               issue_overhead_ns=issue_overhead_ns,
                               posted_writes=posted_writes)
-        cfg, params, dv, ad, wr, ln, sz = eng.prepare(traces)
+        cfg, params, dv, ad, wr, ln, sz = _compact_prepared(
+            eng.prepare(traces))
         if not cfg.fault_hops:
             raise ReplayUnsupported(
                 "fault_seed_sweep needs an active transport fault plan "

@@ -22,6 +22,8 @@ from repro.core.devices import make_device
 from repro.core.fabric import Fabric, MemoryPool
 from repro.core.replay import (MetricsSpec, MultiHostReplay,
                                ShardedMultiHostReplay, build_stack)
+from repro.core.replay.multihost import _compact_prepared
+from repro.core.replay.spec import compact_pages
 from repro.data import WorkloadSpec, host_trace_np, traces_np
 from repro.kernels.cache_sim import cache_sim_fused
 
@@ -84,24 +86,51 @@ def test_cache_sim_fused_compiles_at_paper_geometry(one_chip, num_sets,
     assert _device_bytes(compiled) < HBM_BYTES
 
 
+def _table1_stack(n, counters):
+    """The Table I cached CXL-SSD's stack and a trace of ``n`` accesses,
+    as ``ReplayEngine.run_arrays`` hands them to the device."""
+    addrs, writes = host_trace_np(ZIPF, 0, 0, n)
+    cfg, params = build_stack(
+        make_device("cxl-ssd-cache"), size=64, outstanding=32,
+        issue_overhead_ns=0.5, posted_writes=True, n_accesses=n,
+        max_addr=int(addrs.max()), counters=counters)
+    cfg, addrs = compact_pages(cfg, addrs)
+    return cfg, params, addrs, writes
+
+
 @pytest.fixture(scope="module")
 def table1_scan(one_chip):
-    """The single-host scan of the Table I cached CXL-SSD, compiled."""
+    """The single-host scan of the Table I cached CXL-SSD at
+    ``tableI.zipf``'s shapes (32,768 accesses), compiled."""
     from repro.core.replay.engine import _run_stack
 
-    n = 1 << 16
-    addrs, writes = host_trace_np(ZIPF, 0, 0, n)
+    n = 1 << 15
     with jax.enable_x64(True):
-        cfg, params = build_stack(
-            make_device("cxl-ssd-cache"), size=64, outstanding=32,
-            issue_overhead_ns=0.5, posted_writes=True, n_accesses=n,
-            max_addr=int(addrs.max()), counters=True)
+        cfg, params, _, _ = _table1_stack(n, counters=True)
         compiled = _run_stack.lower(
             cfg, _shapes(params, one_chip),
             jax.ShapeDtypeStruct((n,), jnp.int64, sharding=one_chip),
             jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=one_chip),
             jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip),
             1, MetricsSpec(), True, 64).compile()
+    return cfg, compiled
+
+
+@pytest.fixture(scope="module")
+def table1_sweep(one_chip):
+    """The design sweep at ``tableI.sweep64``'s shapes, compiled: 16,384
+    accesses under 64 designs (32 capacities x LRU/FIFO), vmapped."""
+    from repro.core.replay.sweep import _run_cache_lanes
+
+    n, caps = 1 << 14, np.arange(1, 33, dtype=np.int64) * 128
+    with jax.enable_x64(True):
+        cfg, params, addrs, writes = _table1_stack(n, counters=False)
+        params = {**params, "is_lru": np.repeat([True, False], 32),
+                  "cap": np.tile(caps, 2)}
+        compiled = _run_cache_lanes.lower(
+            cfg, _shapes(params, one_chip),
+            _shapes((addrs, writes), one_chip),
+            frozenset({"is_lru", "cap"}), None).compile()
     return cfg, compiled
 
 
@@ -120,8 +149,8 @@ def mld8_multi(one_chip):
                           metrics=MetricsSpec())
     addrs, writes = traces_np(ZIPF, 0, hosts, n)
     with jax.enable_x64(True):
-        cfg, params, devs, a, w, lens, size = eng.prepare_arrays(addrs,
-                                                                 writes)
+        cfg, params, devs, a, w, lens, size = _compact_prepared(
+            eng.prepare_arrays(addrs, writes))
         compiled = _run_multi.lower(
             cfg, *_shapes((params, devs, a, w, lens, np.int64(0)),
                           one_chip),
@@ -144,7 +173,8 @@ def fleet_sharded(topo):
     eng = ShardedMultiHostReplay(mounts, metrics=MetricsSpec())
     addrs, writes = traces_np(ZIPF, 0, hosts, n)
     with jax.enable_x64(True):
-        cfg, params, _, a, w, lens, size = eng.prepare_arrays(addrs, writes)
+        cfg, params, _, a, w, lens, size = _compact_prepared(
+            eng.prepare_arrays(addrs, writes))
         sh, rep = eng._shard_tensors(cfg, params, lens, a, w)
         run, mesh = _build_runner(cfg, tuple(topo.devices), 1,
                                   MetricsSpec(), True, size)
@@ -187,6 +217,25 @@ def test_table1_scan_has_no_int64_division(runner, request):
     divisions = re.findall(r'op_name="[^"]*jit\((?:floor_divide|remainder)\)',
                            compiled.as_text())
     assert not divisions, f"{len(divisions)} instructions expand a division"
+
+
+@pytest.mark.parametrize("runner,span,pages", [
+    ("mld8_multi", 1 << 22, 1 << 15),
+    ("table1_scan", 1 << 16, 1 << 15),
+    ("table1_sweep", 1 << 16, 1 << 14),
+])
+def test_ftl_map_is_sized_by_the_trace_not_the_span(runner, span, pages,
+                                                   request):
+    """The runner carries the FTL map at the trace's access count, one
+    ``s32[lanes, pages]`` block per flash lane (a design point adds its
+    own axis), and no map at the address span: at ``mld8.zipf``'s shapes
+    a span-sized map is the whole 16 GiB device, 2^22 entries, and keeps
+    the step's small arrays out of the core's fast memory."""
+    cfg, compiled = request.getfixturevalue(runner)
+    hlo = compiled.as_text()
+    assert getattr(cfg, "stack", cfg).num_pages == pages
+    assert re.search(rf"s32\[(\d+,)?1,{pages}\]", hlo)
+    assert not re.search(rf"s32\[(\d+,)*{span}\]", hlo)
 
 
 def test_sharded_fleet_runner_compiles_on_four_chip_mesh(topo,
